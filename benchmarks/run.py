@@ -25,6 +25,10 @@ def main(argv=None):
     args = ap.parse_args(argv)
     only = set(args.only.split(",")) if args.only else None
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     def want(name):
         return only is None or name in only
 

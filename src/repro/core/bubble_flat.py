@@ -41,7 +41,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["BubbleFlat"]
+__all__ = ["BubbleFlat", "DeadSlotError"]
+
+
+class DeadSlotError(RuntimeError):
+    """A block's assignment landed on a dead slot: the block sits outside
+    the table's centered frame.  The table has marked itself stale; the
+    caller applies the block on the host tree and the next use reloads
+    at a fresh origin."""
 
 # same far-away coordinate ops.py uses for padded bubble rows: dead slots
 # park there so no real (centered) point ever selects them in the argmin
@@ -253,7 +260,7 @@ class BubbleFlat:
             # caller must reload (fresh origin) rather than let a -1 leaf
             # id reach the tree as a Python negative index.
             self.stale = True
-            raise RuntimeError(
+            raise DeadSlotError(
                 "flat assignment landed on a dead slot — block is outside "
                 "the centered frame; reload the flat state (fresh origin)"
             )

@@ -421,7 +421,8 @@ def _grid_round_minima(grid, cd, labels, hopeless, views, NT, T, n, bn):
         def body(st):
             t, bw, be = st
             ys, yy, yv, yo = _tile_slices(grid, ordr[t], T)
-            xy = jax.lax.dot_general(xb, ys, (((1,), (1,)), ((), ())))
+            xy = jax.lax.dot_general(xb, ys, (((1,), (1,)), ((), ())),
+                                     precision=jax.lax.Precision.HIGHEST)
             dm = jnp.sqrt(
                 jnp.maximum((xx[:, None] + yy[None, :]) - 2.0 * xy, 0.0)
             )

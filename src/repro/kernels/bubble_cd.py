@@ -7,6 +7,13 @@ step holds one (bn, L) distance strip in VMEM — nothing L×L ever exists
 in HBM — and replaces the sort with ``min_pts`` rounds of masked
 lexicographic-min extraction.
 
+Layout: the whole table stays resident, stored TRANSPOSED as a (dp, L)
+block (features on sublanes, bubbles on lanes) next to (1, L) mass and
+extent rows, so its VMEM footprint is d·L words rather than a lane-padded
+128·L, and every per-bubble vector is lane-dense.  The output is a
+(L, 1) column written in (bn, 1) blocks — the natural layout of a
+lane-axis reduction.
+
 Why extraction is enough: every real bubble carries mass n_b ≥ 1, so the
 cumulative-mass scan of Eq. 6 crosses ``min_pts`` within its first
 ``min_pts`` entries in ascending-(distance, index) order.  Extracting the
@@ -28,6 +35,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import ref as _ref
 
@@ -38,42 +46,44 @@ DEFAULT_BN = 8
 _MASKED = 1e30
 
 
-def _bubble_cd_kernel(x_ref, y_ref, nb_ref, ext_ref, out_ref, *, bn, min_pts, dim):
-    x = x_ref[...]
-    y = y_ref[...]
-    L = y.shape[0]
-    xx = jnp.sum(x * x, axis=-1, keepdims=True)
-    yy = jnp.sum(y * y, axis=-1, keepdims=True).T
+def _bubble_cd_kernel(x_ref, yt_ref, nb_ref, ext_ref, out_ref, *, bn, min_pts, dim):
+    x = x_ref[...]  # (bn, dp) row block
+    yt = yt_ref[...]  # (dp, L) whole table, transposed
+    L = yt.shape[1]
+    xx = jnp.sum(x * x, axis=1, keepdims=True)  # (bn, 1)
+    yy = jnp.sum(yt * yt, axis=0, keepdims=True)  # (1, L)
     xy = jax.lax.dot_general(
-        x, y, dimension_numbers=(((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        x, yt, dimension_numbers=(((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST, preferred_element_type=jnp.float32,
     )
     d = jnp.sqrt(jnp.maximum(xx + yy - 2.0 * xy, 0.0))
     i = pl.program_id(0)
     rows = jax.lax.broadcasted_iota(jnp.int32, (bn, L), 0) + i * bn
     cols = jax.lax.broadcasted_iota(jnp.int32, (bn, L), 1)
     d = jnp.where(rows == cols, 0.0, d)  # self at distance 0 (Def. 1 convention)
-    nb = nb_ref[...].reshape(1, L)
-    ext = ext_ref[...].reshape(1, L)
+    nb = nb_ref[...]  # (1, L)
+    ext = ext_ref[...]
 
     mp = jnp.float32(min_pts)
+    col0 = jnp.zeros((bn, 1), jnp.float32)
     visited = jnp.zeros((bn, L), dtype=bool)
-    csum = jnp.zeros((bn,), jnp.float32)
-    done = jnp.zeros((bn,), dtype=bool)
-    dstar = jnp.zeros((bn,), jnp.float32)
-    before = jnp.zeros((bn,), jnp.float32)
-    nb_c = jnp.ones((bn,), jnp.float32)
-    ext_c = jnp.zeros((bn,), jnp.float32)
-    m = jnp.zeros((bn,), jnp.float32)
-    nb_j = jnp.zeros((bn,), jnp.float32)
-    ext_j = jnp.zeros((bn,), jnp.float32)
+    csum = col0
+    done = jnp.zeros((bn, 1), dtype=bool)
+    dstar = col0
+    before = col0
+    nb_c = col0 + 1.0
+    ext_c = col0
+    m = col0
+    nb_j = col0
+    ext_j = col0
     for _ in range(min_pts):  # static unroll — min_pts bounds the scan prefix
         masked = jnp.where(visited, _MASKED, d)
-        m = jnp.min(masked, axis=1)
-        at_min = masked == m[:, None]
-        j = jnp.min(jnp.where(at_min, cols, L), axis=1)  # stable tie-break
-        hit = cols == j[:, None]
-        nb_j = jnp.sum(jnp.where(hit, nb, 0.0), axis=1)
-        ext_j = jnp.sum(jnp.where(hit, ext, 0.0), axis=1)
+        m = jnp.min(masked, axis=1, keepdims=True)
+        at_min = masked == m
+        j = jnp.min(jnp.where(at_min, cols, L), axis=1, keepdims=True)  # stable tie-break
+        hit = cols == j
+        nb_j = jnp.sum(jnp.where(hit, nb, 0.0), axis=1, keepdims=True)
+        ext_j = jnp.sum(jnp.where(hit, ext, 0.0), axis=1, keepdims=True)
         new_csum = csum + nb_j
         crossed = (~done) & (new_csum >= mp)
         dstar = jnp.where(crossed, m, dstar)
@@ -111,27 +121,30 @@ def bubble_core_distances(
     """(L, dp), (L,), (L,) -> (L,) bubble core distances (Eq. 6).
 
     ``dim`` is the TRUE feature dimensionality (the nnd exponent), which
-    differs from rep.shape[1] once features are lane-padded.
+    differs from rep.shape[1] once features are zero-padded.
     """
-    L, dpad = rep.shape
+    L, dp = rep.shape
     assert L % bn == 0, (L, bn)
-    grid = (L // bn,)
+    rep = rep.astype(jnp.float32)
     kernel = functools.partial(_bubble_cd_kernel, bn=bn, min_pts=int(min_pts), dim=int(dim))
-    return pl.pallas_call(
+    cd = pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(L // bn,),
         in_specs=[
-            pl.BlockSpec((bn, dpad), lambda i: (i, 0)),
-            pl.BlockSpec((L, dpad), lambda i: (0, 0)),
-            pl.BlockSpec((L,), lambda i: (0,)),
-            pl.BlockSpec((L,), lambda i: (0,)),
+            pl.BlockSpec((bn, dp), lambda i: (i, 0)),
+            pl.BlockSpec((dp, L), lambda i: (0, 0)),
+            pl.BlockSpec((1, L), lambda i: (0, 0)),
+            pl.BlockSpec((1, L), lambda i: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((bn,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((L,), jnp.float32),
+        out_specs=pl.BlockSpec((bn, 1), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((L, 1), jnp.float32),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
+        name="bubble_cd",
     )(
-        rep.astype(jnp.float32),
-        rep.astype(jnp.float32),  # row block and full reference table
-        n_b.astype(jnp.float32),
-        extent.astype(jnp.float32),
+        rep,
+        rep.T,  # the whole reference table, bubbles on lanes
+        n_b.astype(jnp.float32).reshape(1, L),
+        extent.astype(jnp.float32).reshape(1, L),
     )
+    return cd[:, 0]

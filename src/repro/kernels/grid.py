@@ -273,7 +273,8 @@ def _cd_block_values(grid, n_b, extent, mp_f, dim, K, NT, T, bn, xs):
     def body(st):
         t, bd, bi = st
         ys, yy, yv, yo = _tile_slices(grid, order[t], T)
-        xy = jax.lax.dot_general(xb, ys, (((1,), (1,)), ((), ())))
+        xy = jax.lax.dot_general(xb, ys, (((1,), (1,)), ((), ())),
+                                 precision=_ref.HIGHEST)
         # exact ref arithmetic: (xx + yy) - 2*xy, clamp, sqrt
         dm = jnp.sqrt(jnp.maximum((xx[:, None] + yy[None, :]) - 2.0 * xy, 0.0))
         dm = jnp.where(yo[None, :] == xo[:, None], 0.0, dm)  # ref's zero diag
@@ -404,7 +405,8 @@ def grid_assign(grid: GridIndex, x, block: int = DEFAULT_BLOCK):
         def body(st):
             t, bm, bj = st
             ys, yy, yv, yo = _tile_slices(grid, ordr[t], T)
-            xy = jax.lax.dot_general(xb, ys, (((1,), (1,)), ((), ())))
+            xy = jax.lax.dot_general(xb, ys, (((1,), (1,)), ((), ())),
+                                     precision=_ref.HIGHEST)
             sqs = yy[None, :] - 2.0 * xy  # ref._nearest's shifted form
             sqs = jnp.where(yv[None, :], sqs, INF)
             m = jnp.min(sqs, axis=1)

@@ -58,6 +58,16 @@ def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
+def kernel_impl(use_ref: bool) -> str:
+    """Name of what serves a dense kernel call: ``"jnp"`` (the reference
+    path), ``"pallas"`` (compiled Mosaic kernels, TPU) or
+    ``"pallas-interpret"`` (the kernel body run by the interpreter —
+    every non-TPU backend)."""
+    if use_ref:
+        return "jnp"
+    return "pallas-interpret" if _interpret() else "pallas"
+
+
 def _use_ref() -> bool:
     return os.environ.get("REPRO_FORCE_REF", "0") == "1"
 
@@ -77,6 +87,7 @@ def _pad_rows(a: jax.Array, mult: int, fill: float = 0.0) -> jax.Array:
 
 
 def _pad_feats(a: jax.Array, mult: int = 128) -> jax.Array:
+    """Zero-pad the feature axis to a multiple of ``mult`` (distance-neutral)."""
     d = a.shape[1]
     p = (-d) % mult
     if p == 0:
@@ -200,21 +211,25 @@ def assign(
         return idx, jnp.sqrt(jnp.maximum(xx + m[:B], 0.0))
     if _resolve_ref(use_ref):
         return _ref.assign_with_dist(x, reps) if with_dist else _ref.assign(x, reps)
-    n = x.shape[0]
-    bn = min(_assign_k.DEFAULT_BN, max(8, 1 << (max(n - 1, 1)).bit_length()))
-    xp = _pad_feats(_pad_rows(x, bn))
-    L = reps.shape[0]
-    p = (-L) % 8
-    if p:
-        far = jnp.full((p, reps.shape[1]), 1e18, dtype=reps.dtype)
-        rp = jnp.concatenate([reps, far], axis=0)
-    else:
-        rp = reps
-    rp = _pad_feats(rp)
-    out = _assign_k.assign(xp, rp, bn=bn, interpret=_interpret(), with_dist=with_dist)
-    if with_dist:
-        return out[0][:n], out[1][:n]
-    return out[:n]
+    n, L = x.shape[0], reps.shape[0]
+    bn = min(_assign_k.DEFAULT_BN, _pow2_rows(n))
+    xf = x.astype(jnp.float32)
+    xp = _pad_feats(_pad_rows(xf, bn), 8)
+    # the rep axis pads to its power-of-two bucket, so a growing table
+    # recompiles per bucket; pad rows sit far away and never win
+    Lp = _pow2_rows(L)
+    rp = reps.astype(jnp.float32)
+    if Lp != L:
+        far = jnp.full((Lp - L, rp.shape[1]), 1e18, dtype=jnp.float32)
+        rp = jnp.concatenate([rp, far], axis=0)
+    rp = _pad_feats(rp, 8)
+    idx, m = _assign_k.assign(
+        xp, rp, bn=bn, bl=min(_assign_k.DEFAULT_BL, Lp), interpret=_interpret())
+    idx = idx[:n]
+    if not with_dist:
+        return idx
+    xx = jnp.sum(xf * xf, axis=-1)
+    return idx, jnp.sqrt(jnp.maximum(xx + m[:n], 0.0))
 
 
 @functools.partial(jax.jit, static_argnames=("min_pts",))
@@ -222,9 +237,17 @@ def _bubble_cd(rep, n_b, extent, min_pts: int):
     return _ref.bubble_core_distances(rep, n_b, extent, min_pts, rep.shape[1])
 
 
-# Above this bubble-table size the (bn, L) strip + full (L, 128) table no
-# longer fit VMEM comfortably; fall back to the jnp scan.
-_BCD_VMEM_LIMIT = 1 << 13
+# Largest bubble table the Eq. 6 strip kernel keeps resident in VMEM
+# (its (bn, L) strips and the transposed (d, L) table; the v5e compile
+# rehearsal holds L = 32768 at d = 16); larger tables take the jnp scan.
+# `bcd_impl` names the choice so callers record it.
+_BCD_VMEM_LIMIT = 1 << 15
+
+
+def bcd_impl(L: int, use_ref: bool) -> str:
+    """What serves Eq. 6 core distances over an L-row table on the dense
+    path (see `kernel_impl`)."""
+    return kernel_impl(use_ref or L > _BCD_VMEM_LIMIT)
 
 
 def bubble_core_distances(
@@ -245,8 +268,7 @@ def bubble_core_distances(
         # mass (knn's k=min(k,m) rule; the strip kernel's extraction
         # prefix relies on it).  Jitted callers see tracers (the int()
         # below raises) and must pre-clamp — offline_recluster_from_table
-        # does.  ConcretizationTypeError is the stable cross-version way
-        # to detect a tracer (jax.core.Tracer moved across releases).
+        # does.  ConcretizationTypeError is how a tracer shows here.
         min_pts = max(1, min(int(min_pts), int(jnp.sum(n_b))))
     except jax.errors.ConcretizationTypeError:
         pass
@@ -264,7 +286,7 @@ def bubble_core_distances(
             extp = _pad_rows(extp, Lp)
         g = build_grid(repp, jnp.arange(Lp) < L)
         return grid_core_distances(g, nbp, extp, int(min_pts), d)[:L]
-    if _resolve_ref(use_ref) or L > _BCD_VMEM_LIMIT:
+    if bcd_impl(L, _resolve_ref(use_ref)) == "jnp":
         return _bubble_cd(rep, n_b, extent, min_pts)
     # shrink blocks toward tiny tables, floor at the f32 sublane count
     bn = max(8, min(_bcd_k.DEFAULT_BN, 1 << (max(L - 1, 1)).bit_length()))
@@ -279,10 +301,35 @@ def bubble_core_distances(
     else:
         repp, nbp, extp = rep, n_b, extent
     cd = _bcd_k.bubble_core_distances(
-        _pad_feats(repp), nbp, extp, min_pts=min_pts, dim=d, bn=bn,
+        _pad_feats(repp, 8), nbp, extp, min_pts=min_pts, dim=d, bn=bn,
         interpret=_interpret(),
     )
     return cd[:L]
+
+
+def offline_stages(Lp: int, use_ref: bool, spatial: bool, mesh=None,
+                   with_w: bool = True) -> dict[str, str]:
+    """What serves each O(L²) stage of `_offline_pipeline` over an Lp-row
+    table — the choices the pipeline makes while it traces, named so the
+    engine can record them (``kernel_impl`` names)."""
+    if mesh is not None:
+        kind = "grid" if spatial else "jnp"
+        return {
+            "core_distances": f"{kind}-sharded",
+            "mutual_reach": "none" if spatial else "jnp-sharded",
+            "mst": f"boruvka-{kind}-sharded",
+        }
+    if spatial:
+        return {
+            "core_distances": "grid",
+            "mutual_reach": "jnp" if with_w else "none",
+            "mst": "boruvka-grid",
+        }
+    return {
+        "core_distances": bcd_impl(Lp, use_ref),
+        "mutual_reach": kernel_impl(use_ref),
+        "mst": "boruvka-dense",
+    }
 
 
 def bubble_mutual_reachability(
@@ -380,18 +427,12 @@ def bubble_table(LS, SS, N, ids):
 
 
 def _shard_map(fn, mesh, in_specs, out_specs):
-    """``shard_map`` across jax versions (>= 0.6 top-level API, older
-    releases ship it in experimental).  Replication checking is disabled:
-    the sharded offline stages deliberately RETURN replicated values —
-    every shard holds identical bits by construction (tiled all_gathers
-    feeding replicated tails) — which the checker cannot see through."""
-    try:
-        smap, check_kw = jax.shard_map, {"check_vma": False}
-    except AttributeError:
-        from jax.experimental.shard_map import shard_map as smap
-
-        check_kw = {"check_rep": False}
-    return smap(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **check_kw)
+    """``jax.shard_map`` with replication checking disabled: the sharded
+    offline stages deliberately RETURN replicated values — every shard
+    holds identical bits by construction (tiled all_gathers feeding
+    replicated tails) — which the checker cannot see through."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def _sharded_mst_stage(rep, n_b, extent, n_valid, min_pts: int, mesh,
@@ -582,6 +623,7 @@ class OfflineClusterResult:
     cluster_weight: np.ndarray  # (K,)
     selected: np.ndarray  # (K,) bool — flat-extraction winners
     all_stabilities: np.ndarray  # (K,) stability of every condensed label
+    stages: dict = dataclasses.field(default_factory=dict)  # offline_stages()
 
     @property
     def n_clusters(self) -> int:
@@ -678,7 +720,7 @@ def offline_recluster_from_table(
     # scan can never reach min_pts and the fallback would land on a
     # padding bubble; clamp to the available mass (knn's k=min(k,m) rule)
     min_pts = max(1, min(int(min_pts), int(Ng.sum())))
-    Lp = max(8, 1 << (max(L - 1, 1)).bit_length())
+    Lp = _pow2_rows(L)
     pad = Lp - L
     if pad:
         rep = np.concatenate([rep, np.full((pad, rep.shape[1]), _PAD_COORD)])
@@ -686,6 +728,9 @@ def offline_recluster_from_table(
         extent = np.concatenate([extent, np.zeros(pad)])
     else:
         Ng_p = Ng
+    # the spatial pass exists to NOT build the (Lp, Lp) matrix; only
+    # materialize it when the caller explicitly asked
+    with_w = ((not spatial_index) or bool(return_w)) and mesh is None
     out = _offline_pipeline(
         jnp.asarray(rep, jnp.float32),
         jnp.asarray(Ng_p, jnp.float32),
@@ -697,14 +742,13 @@ def offline_recluster_from_table(
         method,
         bool(allow_single_cluster),
         spatial=bool(spatial_index),
-        # the spatial pass exists to NOT build the (Lp, Lp) matrix;
-        # only materialize it when the caller explicitly asked
-        with_w=((not spatial_index) or bool(return_w)) and mesh is None,
+        with_w=with_w,
         mesh=mesh,
         mesh_axis=mesh_axis,
     )
     W_dev = out.pop("W", None)
     result = _unwrap_result(out, L, mcs, Ng)
+    result.stages = offline_stages(Lp, use, bool(spatial_index), mesh, with_w)
     if return_w:
         return np.asarray(W_dev)[:L, :L], result
     return result
@@ -744,12 +788,12 @@ def _unwrap_result(out, L: int, mcs: float, weights: np.ndarray) -> OfflineClust
     jax.jit,
     static_argnames=(
         "min_pts", "use_ref", "method", "allow_single", "spatial",
-        "mesh", "mesh_axis",
+        "mesh", "mesh_axis", "rows",
 # trace-contract: device_table_pipeline rules=f32,no-callbacks,pow2,no-dense
     ),
 )
 def _device_table_pipeline(
-    LS, LSe, SS, SSe, N, alive, mcs, min_pts: int, use_ref: bool,
+    LS, LSe, SS, SSe, N, alive, mcs, min_pts: int, use_ref: bool, rows: int,
     method: str = "eom", allow_single: bool = False, spatial: bool = False,
     mesh=None, mesh_axis: str = "data",
 ):
@@ -767,17 +811,22 @@ def _device_table_pipeline(
     With ``mesh``, the compaction/derivation reductions are pinned to
     replicated sharding — the table is small and a GSPMD-split f32 sum
     would change accumulation order, i.e. bits — and only the quadratic
-    stage inside `_offline_pipeline` row-blocks over the mesh."""
+    stage inside `_offline_pipeline` row-blocks over the mesh.
+
+    ``rows`` (static, a power of two ≥ the populated-slot count, at most
+    the slot bucket) is the bucket the compacted table is cut to: the
+    slot table carries ~2x headroom for structural churn, which the
+    quadratic stages must not pay for."""
     if mesh is not None:
         repl = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec())
         LS, LSe, SS, SSe, N, alive = (
             jax.lax.with_sharding_constraint(a, repl)
             for a in (LS, LSe, SS, SSe, N, alive)
         )
-    Lp = LS.shape[0]
+    Lp = rows
     ok = alive & (N > 0)
     n_valid = jnp.sum(ok, dtype=jnp.int32)
-    perm = jnp.argsort(jnp.where(ok, 0, 1), stable=True)
+    perm = jnp.argsort(jnp.where(ok, 0, 1), stable=True)[:Lp]
     LSs = (LS - LSe)[perm]
     SSs = (SS - SSe)[perm]
     Ns = N[perm]
@@ -838,9 +887,12 @@ def offline_recluster_from_device_table(
         LS, LSe, SS, SSe, N, alive = (
             jax.device_put(a, repl) for a in (LS, LSe, SS, SSe, N, alive)
         )
+    # cut the compacted table to the power-of-two bucket of its live slots
+    rows = min(_pow2_rows(int(np.count_nonzero(np.asarray(alive)))),
+               int(LS.shape[0]))
     out = _device_table_pipeline(
         LS, LSe, SS, SSe, N, alive,
-        jnp.asarray(mcs, jnp.float32), int(min_pts), use,
+        jnp.asarray(mcs, jnp.float32), int(min_pts), use, rows,
         method, bool(allow_single_cluster), spatial=bool(spatial_index),
         mesh=mesh, mesh_axis=mesh_axis,
     )
@@ -852,6 +904,8 @@ def offline_recluster_from_device_table(
     nb = out.pop("nb").astype(np.float64)[:L]
     center = out.pop("mu").astype(np.float64) + origin
     result = _unwrap_result(out, L, mcs, nb)
+    result.stages = offline_stages(rows, use, bool(spatial_index), mesh,
+                                   with_w=not spatial_index)
     return result, rep, nb, center
 
 

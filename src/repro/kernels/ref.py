@@ -1,9 +1,18 @@
-"""Pure-jnp oracles for every Pallas kernel (the allclose targets)."""
+"""Pure-jnp oracles for every Pallas kernel (the allclose targets).
+
+Every f32 distance dot here, in the Pallas kernels and in the grid layer
+runs at ``Precision.HIGHEST``: the TPU's default f32 matmul rounds its
+inputs to bfloat16, which the ‖x‖²+‖y‖²−2xy expansion turns into
+distance errors far above the cluster separations.  CPU ignores the
+setting, so CPU bits are unchanged.
+"""
 
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def pairwise_sqdist(x, y):
@@ -11,7 +20,7 @@ def pairwise_sqdist(x, y):
     y = y.astype(jnp.float32)
     xx = jnp.sum(x * x, axis=-1)[:, None]
     yy = jnp.sum(y * y, axis=-1)[None, :]
-    return jnp.maximum(xx + yy - 2.0 * x @ y.T, 0.0)
+    return jnp.maximum(xx + yy - 2.0 * jnp.matmul(x, y.T, precision=HIGHEST), 0.0)
 
 
 def pairwise_dist_pinned(x):
@@ -36,7 +45,7 @@ def pairwise_dist_pinned(x):
     x = x.astype(jnp.float32)
     xx = jax.lax.optimization_barrier(
         jnp.sum(jnp.maximum(x * x, 0.0), axis=-1))
-    xy = jax.lax.optimization_barrier(x @ x.T)
+    xy = jax.lax.optimization_barrier(jnp.matmul(x, x.T, precision=HIGHEST))
     nn = jax.lax.optimization_barrier(xx[:, None] + xx[None, :])
     sq = jnp.maximum(nn - 2.0 * xy, 0.0)
     return jnp.sqrt(jax.lax.optimization_barrier(sq))
@@ -80,7 +89,7 @@ def _nearest(x, reps):
     x = x.astype(jnp.float32)
     r = reps.astype(jnp.float32)
     L = r.shape[0]
-    sq = jnp.sum(r * r, axis=-1)[None, :] - 2.0 * x @ r.T
+    sq = jnp.sum(r * r, axis=-1)[None, :] - 2.0 * jnp.matmul(x, r.T, precision=HIGHEST)
     m = jnp.min(sq, axis=1)
     cols = jax.lax.broadcasted_iota(jnp.int32, sq.shape, 1)
     idx = jnp.min(jnp.where(sq == m[:, None], cols, L), axis=1).astype(jnp.int32)

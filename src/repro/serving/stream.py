@@ -71,8 +71,10 @@ import dataclasses
 import threading
 import time
 
+import jax
 import numpy as np
 
+from repro.core.bubble_flat import DeadSlotError
 from repro.core.bubble_tree import BubbleTree
 from repro.core.device_table import DynamicStateCapture, SnapshotDeviceTable
 from repro.kernels import ops
@@ -301,11 +303,21 @@ class StreamingClusterEngine:
             device_assign = self.backend.name == "pallas"
         assign_fn = None
         if device_assign:
-            # argmin is translation-invariant; center before the f32 kernel
-            # so off-origin coordinates don't cancel (same as the offline path)
+            assign = jax.jit(self.backend.assign)
+
             def assign_fn(X, reps):
+                # argmin is translation-invariant; center before the f32
+                # kernel so off-origin coordinates don't cancel (same as the
+                # offline path).  Both sides pad on the host to power-of-two
+                # buckets, so one compiled program serves every leaf count
+                # in a bucket (pad reps park far away and never win).
                 mu = reps.mean(axis=0)
-                return np.asarray(self.backend.assign(X - mu, reps - mu))
+                Xb = np.zeros((ops._pow2_rows(X.shape[0]), X.shape[1]), np.float32)
+                Xb[: X.shape[0]] = X - mu
+                Rb = np.full((ops._pow2_rows(reps.shape[0]), reps.shape[1]),
+                             ops._PAD_COORD, np.float32)
+                Rb[: reps.shape[0]] = reps - mu
+                return np.asarray(assign(Xb, Rb))[: X.shape[0]]
         self.tree = BubbleTree(  # owner: ingest thread (workers read captures)
             dim=dim, compression=compression, assign_fn=assign_fn, **tree_kw
         )
@@ -371,6 +383,12 @@ class StreamingClusterEngine:
         self._query_engine = QueryEngine(
             self.backend, dim, cache=query_cache, scope=query_scope
         )
+        # which implementation serves each plane (ops.kernel_impl names):
+        # fixed at construction for ingest and queries, recorded per pass
+        # for the offline stages (ops.offline_stages)
+        dense = ops.kernel_impl(self.backend.use_ref)
+        device_impl = "grid" if self.backend.spatial_index else dense
+        ingest_impl = device_impl if (device_online or device_assign) else "host"
         # unsynchronized: single-reference swap; readers take ONE read of
         # the (key, payload) tuple (see labels()) so entries never mix
         self._labels_cache: tuple | None = None
@@ -390,6 +408,9 @@ class StreamingClusterEngine:
             "device_online_blocks": 0,
             "flat_loads": 0,
             "label_cache_hits": 0,
+            "ingest_impl": ingest_impl,
+            "query_impl": device_impl,
+            "offline_impl": {},
         }
 
     # -- request plane -----------------------------------------------------
@@ -513,13 +534,11 @@ class StreamingClusterEngine:
         cap = self.tree._leaf_cap_at(self.tree.n_points + X.shape[0])
         try:
             leaf_ids, work = self._flat.insert_block(X, cap)
-        except RuntimeError:
-            # dead-slot guard (stream drifted outside the centered frame)
-            # or a device failure mid-dispatch: either way the flat table
-            # did not absorb this block, so it MUST reload before the next
-            # scatter or ε-pass (the guard sets stale itself; a raw XLA
-            # RuntimeError would not) — then apply via the host path
-            self._flat.stale = True
+        except DeadSlotError:
+            # the stream drifted outside the centered frame: the table has
+            # marked itself stale and reloads at a fresh origin before the
+            # next scatter or ε-pass; this block goes through the host
+            # path.  Device and compile errors are not caught here.
             return self.tree.insert_block(X)
         pids = self.tree.apply_assigned_block(X, leaf_ids, overfull_hint=work)
         self._flat.sync_struct(self.tree)
@@ -748,6 +767,7 @@ class StreamingClusterEngine:
             self._snapshot = snap
         self.stats["recluster_count"] += 1
         self.stats["offline_seconds_total"] += wall
+        self.stats["offline_impl"] = dict(res.stages)
         return snap
 
     def flush(self) -> ClusterSnapshot | None:

@@ -155,7 +155,8 @@ def _device_table_point(L_raw: int, mesh_size: int = 1) -> LatticePoint:
         )
         mesh = jax.make_mesh((mesh_size,), ("data",)) if mesh_size > 1 else None
         kw = {"mesh": mesh} if mesh is not None else {}
-        return jax.make_jaxpr(lambda *a: ops._device_table_pipeline(*a, 5, True, **kw))(*args)
+        return jax.make_jaxpr(
+            lambda *a: ops._device_table_pipeline(*a, 5, True, Lp, **kw))(*args)
 
     Lp = _pow2(L_raw)
     return LatticePoint(

@@ -82,7 +82,7 @@ def _source_loc(eqn) -> str:
     try:
         from jax._src import source_info_util as siu
 
-        frame = siu.user_frame(eqn.source_info)
+        frame = siu.user_frame(eqn.source_info.traceback)
         if frame is None:
             return "?"
         name = frame.file_name
@@ -198,11 +198,11 @@ def probe_x64(fn: Callable[[], Any], *, label: str) -> list[AvalHit] | str:
     platform-int accumulations) is deliberately ignored — the f32-only
     contract is about float math.
     """
-    from jax.experimental import enable_x64
+    import jax
 
     hits: list[AvalHit] = []
     try:
-        with enable_x64():
+        with jax.enable_x64():
             closed = fn()
     except Exception as e:  # noqa: BLE001
         return f"{type(e).__name__}: {e}"
