@@ -26,6 +26,8 @@ from __future__ import annotations
 
 import numpy as np
 
+from repro.spans import span
+
 from .bubbles import DataBubbles, bubbles_from_cf
 from .cf import CFTable
 
@@ -70,6 +72,12 @@ class BubbleTree:
         # changes a block-level device mirror (core.bubble_flat) cannot
         # reproduce from the block's own scatter; it patches these rows.
         self._struct_dirty: set[int] = set()
+        # structural changes made (Algorithm 1's steps and the block
+        # paths' dissolves), and seconds inside each block-path span
+        # (repro.spans): counters the engine mirrors into its stats
+        self.splits = 0
+        self.dissolves = 0
+        self.span_s: dict[str, float] = {}
 
         # --- node SoA ---
         cap = capacity
@@ -285,24 +293,25 @@ class BubbleTree:
         if i == X.shape[0]:
             return pids
         rest = X[i:]
-        leaf_ids = self.alive_leaf_ids()
-        reps = self.LS[leaf_ids] / np.maximum(self.N[leaf_ids], 1.0)[:, None]
-        if self._assign_fn is not None:
-            assign = np.asarray(self._assign_fn(rest, reps))
-        else:
-            # center exactly like the engine's device assign_fn: argmin is
-            # translation-invariant, and the ‖x‖²+‖r‖²−2xr expansion
-            # cancels catastrophically off-origin (even f64 runs out of
-            # mantissa once coordinates dwarf the separations)
-            mu = reps.mean(axis=0)
-            Xc = rest - mu
-            Rc = reps - mu
-            sq = (
-                np.einsum("id,id->i", Xc, Xc)[:, None]
-                + np.einsum("jd,jd->j", Rc, Rc)[None, :]
-                - 2.0 * Xc @ Rc.T
-            )
-            assign = np.argmin(sq, axis=1)
+        with span("ingest.assign", self.span_s):
+            leaf_ids = self.alive_leaf_ids()
+            reps = self.LS[leaf_ids] / np.maximum(self.N[leaf_ids], 1.0)[:, None]
+            if self._assign_fn is not None:
+                assign = np.asarray(self._assign_fn(rest, reps))
+            else:
+                # center exactly like the engine's device assign_fn: argmin
+                # is translation-invariant, and the ‖x‖²+‖r‖²−2xr expansion
+                # cancels catastrophically off-origin (even f64 runs out of
+                # mantissa once coordinates dwarf the separations)
+                mu = reps.mean(axis=0)
+                Xc = rest - mu
+                Rc = reps - mu
+                sq = (
+                    np.einsum("id,id->i", Xc, Xc)[:, None]
+                    + np.einsum("jd,jd->j", Rc, Rc)[None, :]
+                    - 2.0 * Xc @ Rc.T
+                )
+                assign = np.argmin(sq, axis=1)
         return pids + self.apply_assigned_block(rest, leaf_ids[assign])
 
     def apply_assigned_block(self, X, leaf_per_row, overfull_hint=None) -> list[int]:
@@ -318,25 +327,26 @@ class BubbleTree:
         leaf_per_row = np.asarray(leaf_per_row, dtype=np.int64)
         n = X.shape[0]
         assert leaf_per_row.shape == (n,)
-        pids = self._new_points(X)
-        pid_arr = np.asarray(pids, dtype=np.int64)
-        self.point_leaf[pid_arr] = leaf_per_row
-        # segment-reduce the CF deltas: one reduceat per statistic beats a
-        # Python loop over touched leaves by ~an order of magnitude
-        order = np.argsort(leaf_per_row, kind="stable")
-        sorted_leaves = leaf_per_row[order]
-        uniq, starts = np.unique(sorted_leaves, return_index=True)
-        Xs = X[order]
-        self.LS[uniq] += np.add.reduceat(Xs, starts, axis=0)
-        self.SS[uniq] += np.add.reduceat(np.einsum("nd,nd->n", Xs, Xs), starts)
-        counts = np.diff(np.append(starts, n))
-        self.N[uniq] += counts
-        sorted_pids = pid_arr[order]
-        off = 0
-        for leaf, cnt in zip(uniq, counts):
-            self.leaf_points[int(leaf)].extend(sorted_pids[off : off + cnt].tolist())
-            off += int(cnt)
-        self._recompute_internal_cfs()
+        with span("ingest.cf", self.span_s):
+            pids = self._new_points(X)
+            pid_arr = np.asarray(pids, dtype=np.int64)
+            self.point_leaf[pid_arr] = leaf_per_row
+            # segment-reduce the CF deltas: one reduceat per statistic beats
+            # a Python loop over touched leaves by ~an order of magnitude
+            order = np.argsort(leaf_per_row, kind="stable")
+            sorted_leaves = leaf_per_row[order]
+            uniq, starts = np.unique(sorted_leaves, return_index=True)
+            Xs = X[order]
+            self.LS[uniq] += np.add.reduceat(Xs, starts, axis=0)
+            self.SS[uniq] += np.add.reduceat(np.einsum("nd,nd->n", Xs, Xs), starts)
+            counts = np.diff(np.append(starts, n))
+            self.N[uniq] += counts
+            sorted_pids = pid_arr[order]
+            off = 0
+            for leaf, cnt in zip(uniq, counts):
+                self.leaf_points[int(leaf)].extend(sorted_pids[off : off + cnt].tolist())
+                off += int(cnt)
+            self._recompute_internal_cfs()
         self.n_points += n
         self.dirty_mass += float(n)
         self.mutations += 1
@@ -346,7 +356,8 @@ class BubbleTree:
             and self.num_leaves == self.target_L
         ):
             return pids
-        self._maintain_to_fixpoint()
+        with span("ingest.maintain", self.span_s):
+            self._maintain_to_fixpoint()
         return pids
 
     def delete_block(self, pids):
@@ -362,40 +373,42 @@ class BubbleTree:
         if len(pids) == 1:
             self.delete(pids[0])
             return
-        seen: set[int] = set()
-        for pid in pids:  # validate before any mutation: reject whole block
-            if not (0 <= pid < self.point_alive.shape[0]) or not self.point_alive[pid]:
-                raise KeyError(f"point {pid} not alive")
-            if pid in seen:
-                raise KeyError(f"point {pid} duplicated in delete block")
-            seen.add(pid)
-        by_leaf: dict[int, list[int]] = {}
-        for pid in pids:
-            by_leaf.setdefault(int(self.point_leaf[pid]), []).append(pid)
-            self.point_alive[pid] = False
-        for leaf, victims in by_leaf.items():
-            gone = set(victims)
-            self.leaf_points[leaf] = [q for q in self.leaf_points[leaf] if q not in gone]
-            P = self.PX[np.asarray(victims, dtype=np.int64)]
-            self.LS[leaf] -= P.sum(axis=0)
-            self.SS[leaf] -= float(np.einsum("nd,nd->", P, P))
-            self.N[leaf] -= float(len(victims))
-            for pid in victims:
-                self.point_leaf[pid] = -1
-                self._point_free.append(pid)
-        self._recompute_internal_cfs()
+        with span("ingest.cf", self.span_s):
+            seen: set[int] = set()
+            for pid in pids:  # validate before any mutation: reject whole block
+                if not (0 <= pid < self.point_alive.shape[0]) or not self.point_alive[pid]:
+                    raise KeyError(f"point {pid} not alive")
+                if pid in seen:
+                    raise KeyError(f"point {pid} duplicated in delete block")
+                seen.add(pid)
+            by_leaf: dict[int, list[int]] = {}
+            for pid in pids:
+                by_leaf.setdefault(int(self.point_leaf[pid]), []).append(pid)
+                self.point_alive[pid] = False
+            for leaf, victims in by_leaf.items():
+                gone = set(victims)
+                self.leaf_points[leaf] = [q for q in self.leaf_points[leaf] if q not in gone]
+                P = self.PX[np.asarray(victims, dtype=np.int64)]
+                self.LS[leaf] -= P.sum(axis=0)
+                self.SS[leaf] -= float(np.einsum("nd,nd->", P, P))
+                self.N[leaf] -= float(len(victims))
+                for pid in victims:
+                    self.point_leaf[pid] = -1
+                    self._point_free.append(pid)
+            self._recompute_internal_cfs()
         self.n_points -= len(pids)
         self.dirty_mass += float(len(pids))
         self.mutations += 1
-        for leaf in list(by_leaf):
-            if (
-                self.node_alive[leaf]
-                and self.is_leaf[leaf]
-                and len(self.leaf_points[leaf]) < self.m
-                and self.num_leaves > 1
-            ):
-                self._dissolve_leaf(leaf)
-        self._maintain_to_fixpoint()
+        with span("ingest.maintain", self.span_s):
+            for leaf in list(by_leaf):
+                if (
+                    self.node_alive[leaf]
+                    and self.is_leaf[leaf]
+                    and len(self.leaf_points[leaf]) < self.m
+                    and self.num_leaves > 1
+                ):
+                    self._dissolve_leaf(leaf)
+            self._maintain_to_fixpoint()
 
     # ------------------------------------------------------------------
     # insertion internals
@@ -533,6 +546,7 @@ class BubbleTree:
             self.root = new_root
             par = new_root
         self._attach_node(sib, par)
+        self.splits += 1
         return sib
 
     def _split_internal(self, nid: int):
@@ -594,6 +608,7 @@ class BubbleTree:
             self.root = only
 
     def _dissolve_leaf(self, leaf: int):
+        self.dissolves += 1
         pts = list(self.leaf_points[leaf])
         self.leaf_points[leaf] = []
         self._struct_dirty.add(leaf)

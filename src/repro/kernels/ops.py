@@ -27,6 +27,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.spans import span
+
 from . import assign as _assign_k
 from . import bubble_cd as _bcd_k
 from . import knn as _knn_k
@@ -479,11 +481,14 @@ def _sharded_mst_stage(rep, n_b, extent, n_valid, min_pts: int, mesh,
     extent = jax.lax.with_sharding_constraint(extent, repl)
 
     if spatial:
-        grid = build_grid(rep, jnp.arange(Lp) < n_valid)
+        with jax.named_scope("core_distance"):
+            grid = build_grid(rep, jnp.arange(Lp) < n_valid)
 
         def stage(grid, n_b, extent):
-            cd = grid_core_distances_shard(grid, n_b, extent, min_pts, d, axis, k)
-            return boruvka_grid_shard_jax(grid, cd, axis, k)
+            with jax.named_scope("core_distance"):
+                cd = grid_core_distances_shard(grid, n_b, extent, min_pts, d, axis, k)
+            with jax.named_scope("mst"):
+                return boruvka_grid_shard_jax(grid, cd, axis, k)
 
         f = _shard_map(stage, mesh, in_specs=(P(), P(), P()),
                        out_specs=(P(), P(), P(), P()))
@@ -491,30 +496,33 @@ def _sharded_mst_stage(rep, n_b, extent, n_valid, min_pts: int, mesh,
         return eu[:Lp], ev[:Lp], ew[:Lp], valid[:Lp]
 
     def stage(rep_f, n_b_f, extent_f, n_valid_):
-        # replicated (Lp, Lp) distance matrix, every intermediate pinned
-        # (ref.pairwise_dist_pinned) so the bits cannot depend on the
-        # mesh-shaped consumers this program inlines it next to
-        dm = _ref.pairwise_dist_pinned(rep_f)
-        nb_l, ext_l = n_b_f, extent_f
-        if n != Lp:  # exact lift of the materialized matrix
-            dm = jnp.pad(dm, ((0, n - Lp), (0, n - Lp)),
-                         constant_values=jnp.inf)
-            nb_l = jnp.pad(n_b_f, (0, n - Lp))
-            ext_l = jnp.pad(extent_f, (0, n - Lp))
-        m = n // k
-        i0 = jax.lax.axis_index(axis).astype(jnp.int32) * m
-        rows = i0 + jnp.arange(m, dtype=jnp.int32)
-        dm_s = jax.lax.dynamic_slice_in_dim(dm, i0, m, 0)
-        cd_s = _ref.bubble_core_distances_from_dm(
-            dm_s, rows, nb_l, ext_l, min_pts, d)
-        cd = jax.lax.all_gather(cd_s, axis, tiled=True)
-        W_s = jnp.maximum(dm_s, jnp.maximum(cd_s[:, None], cd[None, :]))
-        cols = jnp.arange(n, dtype=jnp.int32)
-        W_s = jnp.where(rows[:, None] == cols[None, :], 0.0, W_s)
-        pad_r = rows >= n_valid_
-        pad_c = cols >= n_valid_
-        W_s = jnp.where(pad_r[:, None] | pad_c[None, :], jnp.inf, W_s)
-        return boruvka_shard_jax(W_s, n, axis)
+        with jax.named_scope("core_distance"):
+            # replicated (Lp, Lp) distance matrix, every intermediate pinned
+            # (ref.pairwise_dist_pinned) so the bits cannot depend on the
+            # mesh-shaped consumers this program inlines it next to
+            dm = _ref.pairwise_dist_pinned(rep_f)
+            nb_l, ext_l = n_b_f, extent_f
+            if n != Lp:  # exact lift of the materialized matrix
+                dm = jnp.pad(dm, ((0, n - Lp), (0, n - Lp)),
+                             constant_values=jnp.inf)
+                nb_l = jnp.pad(n_b_f, (0, n - Lp))
+                ext_l = jnp.pad(extent_f, (0, n - Lp))
+            m = n // k
+            i0 = jax.lax.axis_index(axis).astype(jnp.int32) * m
+            rows = i0 + jnp.arange(m, dtype=jnp.int32)
+            dm_s = jax.lax.dynamic_slice_in_dim(dm, i0, m, 0)
+            cd_s = _ref.bubble_core_distances_from_dm(
+                dm_s, rows, nb_l, ext_l, min_pts, d)
+            cd = jax.lax.all_gather(cd_s, axis, tiled=True)
+        with jax.named_scope("mutual_reach"):
+            W_s = jnp.maximum(dm_s, jnp.maximum(cd_s[:, None], cd[None, :]))
+            cols = jnp.arange(n, dtype=jnp.int32)
+            W_s = jnp.where(rows[:, None] == cols[None, :], 0.0, W_s)
+            pad_r = rows >= n_valid_
+            pad_c = cols >= n_valid_
+            W_s = jnp.where(pad_r[:, None] | pad_c[None, :], jnp.inf, W_s)
+        with jax.named_scope("mst"):
+            return boruvka_shard_jax(W_s, n, axis)
 
     f = _shard_map(stage, mesh, in_specs=(P(), P(), P(), P()),
                    out_specs=(P(), P(), P(), P()))
@@ -551,7 +559,11 @@ def _offline_pipeline(
     row-block sharded over ``mesh_axis`` under shard_map
     (`_sharded_mst_stage`) and the small hierarchy stage runs replicated
     on its gathered edge buffers; results are bitwise the single-device
-    path (never W — mesh callers must not ask for it)."""
+    path (never W — mesh callers must not ask for it).
+
+    Each stage runs under a `jax.named_scope` from `repro.spans.STAGES`:
+    op-name metadata only, so a device trace can sum the time of each
+    stage while the HLO instruction names stay as they were."""
     from repro.core.hierarchy_jax import hierarchy_fixed
     from repro.core.mst import boruvka_grid_jax, boruvka_jax
 
@@ -569,21 +581,30 @@ def _offline_pipeline(
         # the quadratic memory/compute goes away
         from repro.kernels.grid import build_grid, grid_core_distances
 
-        grid = build_grid(rep, ~is_pad)
-        cd = grid_core_distances(grid, n_b, extent, min_pts, rep.shape[1])
-        eu, ev, ew, valid = boruvka_grid_jax(grid, cd)
+        with jax.named_scope("core_distance"):
+            grid = build_grid(rep, ~is_pad)
+            cd = grid_core_distances(grid, n_b, extent, min_pts, rep.shape[1])
+        with jax.named_scope("mst"):
+            eu, ev, ew, valid = boruvka_grid_jax(grid, cd)
         if with_w:
-            W = mutual_reachability(rep, rep, cd, cd, zero_diag=True, use_ref=True)
-            out["W"] = jnp.where(is_pad[:, None] | is_pad[None, :], jnp.inf, W)
+            with jax.named_scope("mutual_reach"):
+                W = mutual_reachability(rep, rep, cd, cd, zero_diag=True, use_ref=True)
+                out["W"] = jnp.where(is_pad[:, None] | is_pad[None, :], jnp.inf, W)
     else:
-        W = bubble_mutual_reachability(rep, n_b, extent, min_pts, use_ref=use_ref)
-        W = jnp.where(is_pad[:, None] | is_pad[None, :], jnp.inf, W)
-        eu, ev, ew, valid = boruvka_jax(W)
+        # bubble_mutual_reachability's two steps, each under its own scope
+        with jax.named_scope("core_distance"):
+            cd = bubble_core_distances(rep, n_b, extent, min_pts, use_ref=use_ref)
+        with jax.named_scope("mutual_reach"):
+            W = mutual_reachability(rep, rep, cd, cd, zero_diag=True, use_ref=use_ref)
+            W = jnp.where(is_pad[:, None] | is_pad[None, :], jnp.inf, W)
+        with jax.named_scope("mst"):
+            eu, ev, ew, valid = boruvka_jax(W)
         out["W"] = W
-    slt, ct, ex = hierarchy_fixed(
-        eu, ev, ew, valid, n_valid, n_b, mcs,
-        method=method, allow_single_cluster=allow_single,
-    )
+    with jax.named_scope("hierarchy"):
+        slt, ct, ex = hierarchy_fixed(
+            eu, ev, ew, valid, n_valid, n_b, mcs,
+            method=method, allow_single_cluster=allow_single,
+        )
     out.update({
         "eu": eu, "ev": ev, "ew": ew, "valid": valid,
         "labels": ex.labels,
@@ -624,6 +645,9 @@ class OfflineClusterResult:
     selected: np.ndarray  # (K,) bool — flat-extraction winners
     all_stabilities: np.ndarray  # (K,) stability of every condensed label
     stages: dict = dataclasses.field(default_factory=dict)  # offline_stages()
+    # seconds of the "offline.program" span: the device program's call
+    # through its result fetch (None on paths that have no such span)
+    program_s: float | None = None
 
     @property
     def n_clusters(self) -> int:
@@ -731,24 +755,27 @@ def offline_recluster_from_table(
     # the spatial pass exists to NOT build the (Lp, Lp) matrix; only
     # materialize it when the caller explicitly asked
     with_w = ((not spatial_index) or bool(return_w)) and mesh is None
-    out = _offline_pipeline(
-        jnp.asarray(rep, jnp.float32),
-        jnp.asarray(Ng_p, jnp.float32),
-        jnp.asarray(extent, jnp.float32),
-        jnp.asarray(L, jnp.int32),
-        jnp.asarray(mcs, jnp.float32),
-        int(min_pts),
-        use,
-        method,
-        bool(allow_single_cluster),
-        spatial=bool(spatial_index),
-        with_w=with_w,
-        mesh=mesh,
-        mesh_axis=mesh_axis,
-    )
-    W_dev = out.pop("W", None)
+    with span("offline.program") as sp:
+        out = _offline_pipeline(
+            jnp.asarray(rep, jnp.float32),
+            jnp.asarray(Ng_p, jnp.float32),
+            jnp.asarray(extent, jnp.float32),
+            jnp.asarray(L, jnp.int32),
+            jnp.asarray(mcs, jnp.float32),
+            int(min_pts),
+            use,
+            method,
+            bool(allow_single_cluster),
+            spatial=bool(spatial_index),
+            with_w=with_w,
+            mesh=mesh,
+            mesh_axis=mesh_axis,
+        )
+        W_dev = out.pop("W", None)
+        out = jax.device_get(out)  # ONE host sync for all result buffers
     result = _unwrap_result(out, L, mcs, Ng)
     result.stages = offline_stages(Lp, use, bool(spatial_index), mesh, with_w)
+    result.program_s = sp.seconds
     if return_w:
         return np.asarray(W_dev)[:L, :L], result
     return result
@@ -890,14 +917,15 @@ def offline_recluster_from_device_table(
     # cut the compacted table to the power-of-two bucket of its live slots
     rows = min(_pow2_rows(int(np.count_nonzero(np.asarray(alive)))),
                int(LS.shape[0]))
-    out = _device_table_pipeline(
-        LS, LSe, SS, SSe, N, alive,
-        jnp.asarray(mcs, jnp.float32), int(min_pts), use, rows,
-        method, bool(allow_single_cluster), spatial=bool(spatial_index),
-        mesh=mesh, mesh_axis=mesh_axis,
-    )
-    out.pop("W", None)  # fused path never transfers the (Lp, Lp) matrix to host
-    out = jax.device_get(out)
+    with span("offline.program") as sp:
+        out = _device_table_pipeline(
+            LS, LSe, SS, SSe, N, alive,
+            jnp.asarray(mcs, jnp.float32), int(min_pts), use, rows,
+            method, bool(allow_single_cluster), spatial=bool(spatial_index),
+            mesh=mesh, mesh_axis=mesh_axis,
+        )
+        out.pop("W", None)  # fused path never transfers the (Lp, Lp) matrix to host
+        out = jax.device_get(out)
     L = int(out.pop("n_valid"))
     origin = np.asarray(origin, dtype=np.float64)
     rep = out.pop("rep").astype(np.float64)[:L] + origin[None, :]
@@ -906,6 +934,7 @@ def offline_recluster_from_device_table(
     result = _unwrap_result(out, L, mcs, nb)
     result.stages = offline_stages(rows, use, bool(spatial_index), mesh,
                                    with_w=not spatial_index)
+    result.program_s = sp.seconds
     return result, rep, nb, center
 
 

@@ -67,6 +67,7 @@ import numpy as np
 
 from repro.kernels import ops
 from repro.kernels.grid import GridIndex, build_grid, grid_assign
+from repro.spans import span
 
 from .engine import HostBatcher
 
@@ -277,7 +278,8 @@ class SnapshotDeviceCache:
             # (entry installed, or the build failed and the key is free)
             ev.wait()
         try:
-            e = _build_entry(snap, self.spatial)  # unlocked: O(L·d) + upload
+            with span("query.entry_build"):
+                e = _build_entry(snap, self.spatial)  # unlocked: O(L·d) + upload
         except BaseException:
             with self._lock:
                 del self._building[k]
@@ -303,6 +305,9 @@ class QueryResult:
     distance: np.ndarray  # (n,) f64 distance to that representative
     strength: np.ndarray  # (n,) f64 membership strength in [0, 1]
     version: int  # snapshot version served (0 = none yet)
+    # seconds in the "query.program" spans of the call that served these
+    # rows (upload, fused program, fetch); None when no program ran
+    program_s: float | None = None
 
     def __len__(self) -> int:
         return int(self.labels.shape[0])
@@ -348,25 +353,27 @@ class QueryEngine:
             return _empty_result(n, 0 if snap is None else snap.version)
         entry = self.cache.entry(snap, key=self._cache_key(snap.version))
         parts = []
+        program_s = 0.0
         for c0 in range(0, n, _MAX_CHUNK):
             Xr = X[c0 : c0 + _MAX_CHUNK]
             m = Xr.shape[0]
             Bp = _bucket(m)
             xc = np.zeros((Bp, self.dim), dtype=np.float32)
             xc[:m] = Xr - entry.center[None, :]
-            if entry.grid is not None:
-                out = _fused_query_grid(
-                    jnp.asarray(xc), entry.grid, entry.labels, entry.lam,
-                    entry.lam_max,
-                )
-            else:
-                out = _fused_query(
-                    jnp.asarray(xc), entry.reps, entry.labels, entry.lam,
-                    entry.lam_max, self.backend.use_ref,
-                )
-            idx, lbl, dist, strength = (
-                a[:m].copy() for a in jax.device_get(out)  # ONE host sync
-            )
+            with span("query.program") as sp:
+                if entry.grid is not None:
+                    out = _fused_query_grid(
+                        jnp.asarray(xc), entry.grid, entry.labels, entry.lam,
+                        entry.lam_max,
+                    )
+                else:
+                    out = _fused_query(
+                        jnp.asarray(xc), entry.reps, entry.labels, entry.lam,
+                        entry.lam_max, self.backend.use_ref,
+                    )
+                out = jax.device_get(out)  # ONE host sync
+            program_s += sp.seconds
+            idx, lbl, dist, strength = (a[:m].copy() for a in out)
             # a query out past _PAD_COORD can land on an L-bucket pad row:
             # it must surface as "no bubble" (the _empty_result convention),
             # never as a fictitious row ≥ n_bubbles with distance ~0
@@ -384,6 +391,7 @@ class QueryEngine:
             distance=dist.astype(np.float64),
             strength=strength.astype(np.float64),
             version=int(snap.version),
+            program_s=program_s,
         )
 
     def query(self, snap, X) -> np.ndarray:
@@ -531,6 +539,7 @@ class QueryBatcher:
                             distance=res.distance[sl],
                             strength=res.strength[sl],
                             version=res.version,
+                            program_s=res.program_s,
                         )
                     )
                     off += x.shape[0]

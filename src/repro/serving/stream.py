@@ -79,6 +79,7 @@ from repro.core.bubble_tree import BubbleTree
 from repro.core.device_table import DynamicStateCapture, SnapshotDeviceTable
 from repro.kernels import ops
 from repro.launch.mesh import resolve_mesh
+from repro.spans import span
 
 from .engine import HostBatcher
 from .query import QueryEngine, QueryResult
@@ -321,6 +322,11 @@ class StreamingClusterEngine:
         self.tree = BubbleTree(  # owner: ingest thread (workers read captures)
             dim=dim, compression=compression, assign_fn=assign_fn, **tree_kw
         )
+        # seconds inside each span (repro.spans): the tree's block spans and
+        # the engine's share one dict
+        # unsynchronized: the ingest thread and the offline worker add to
+        # different keys (single dict stores, GIL-atomic); readers copy it
+        self.span_s = self.tree.span_s
         self.min_pts = int(min_pts)
         self.min_cluster_size = float(
             min_pts if min_cluster_size is None else min_cluster_size
@@ -408,6 +414,16 @@ class StreamingClusterEngine:
             "device_online_blocks": 0,
             "flat_loads": 0,
             "label_cache_hits": 0,
+            # mirrored from the tree and `span_s` at the end of each poll and
+            # join (`span_s` as a fresh dict: a copy of stats keeps its reading)
+            "tree_splits": 0,
+            "tree_dissolves": 0,
+            "span_s": {},
+            # host clock and the ingest thread's CPU time around poll's
+            # block-apply loop (the ε-pass excluded): their ratio says
+            # whether a slow stretch was work or waiting
+            "ingest_wall_s_total": 0.0,
+            "ingest_cpu_s_total": 0.0,
             "ingest_impl": ingest_impl,
             "query_impl": device_impl,
             "offline_impl": {},
@@ -439,6 +455,21 @@ class StreamingClusterEngine:
         """Drain the request queue: coalesce contiguous same-kind requests
         into blocks (≤ max_block points each), apply them to the tree, then
         consult the staleness policy.  Returns the number of ops applied."""
+        with span("stream.poll"):
+            try:
+                # host clock and thread CPU time of the block-apply loop
+                wall0, cpu0 = time.perf_counter(), time.thread_time()
+                try:
+                    applied = self._apply_blocks(max_blocks)
+                finally:
+                    self.stats["ingest_wall_s_total"] += time.perf_counter() - wall0
+                    self.stats["ingest_cpu_s_total"] += time.thread_time() - cpu0
+                self.maybe_recluster()
+            finally:
+                self._mirror_counters()
+        return applied
+
+    def _apply_blocks(self, max_blocks: int | None) -> int:
         applied = 0
         blocks = 0
         while self.batcher and (max_blocks is None or blocks < max_blocks):
@@ -483,8 +514,13 @@ class StreamingClusterEngine:
                     applied += len(flat_pids)
             self.stats["blocks_applied"] += 1
             blocks += 1
-        self.maybe_recluster()
         return applied
+
+    def _mirror_counters(self):
+        """Copy the tree's counters and the span seconds into the stats."""
+        self.stats["tree_splits"] = self.tree.splits
+        self.stats["tree_dissolves"] = self.tree.dissolves
+        self.stats["span_s"] = dict(self.span_s)
 
     @staticmethod
     def _point_count(item) -> int:
@@ -533,7 +569,8 @@ class StreamingClusterEngine:
             self._flat.load(self.tree)
         cap = self.tree._leaf_cap_at(self.tree.n_points + X.shape[0])
         try:
-            leaf_ids, work = self._flat.insert_block(X, cap)
+            with span("ingest.assign", self.span_s):
+                leaf_ids, work = self._flat.insert_block(X, cap)
         except DeadSlotError:
             # the stream drifted outside the centered frame: the table has
             # marked itself stale and reloads at a fresh origin before the
@@ -625,18 +662,19 @@ class StreamingClusterEngine:
             return False
         t0 = time.perf_counter()
         dirty_captured = self.tree.dirty_mass
-        if self._dyn_stale or not self._dyn.ok or self._dyn.n != n:
-            self._rebuild_dyn()
-        # snapshot rows = ascending device slot; the pipeline gathers the
-        # serve-plane representatives on device, so the per-poll refresh
-        # is ONE host sync — no tree gather, no pid-map inversion, no
-        # padded-buffer re-transfer
-        cap = DynamicStateCapture(state=self._dyn.state, dim=self.tree.dim)
-        res, rep, n_b, center = cap.recluster(
-            self.backend, min_pts=self.min_pts,
-            min_cluster_size=self.min_cluster_size,
-        )
-        self._publish_snapshot(res, rep, n_b, center, n, dirty_captured, t0)
+        with span("offline.pass", self.span_s):
+            if self._dyn_stale or not self._dyn.ok or self._dyn.n != n:
+                self._rebuild_dyn()
+            # snapshot rows = ascending device slot; the pipeline gathers the
+            # serve-plane representatives on device, so the per-poll refresh
+            # is ONE host sync — no tree gather, no pid-map inversion, no
+            # padded-buffer re-transfer
+            cap = DynamicStateCapture(state=self._dyn.state, dim=self.tree.dim)
+            res, rep, n_b, center = cap.recluster(
+                self.backend, min_pts=self.min_pts,
+                min_cluster_size=self.min_cluster_size,
+            )
+            self._publish_snapshot(res, rep, n_b, center, n, dirty_captured, t0)
         self._settle()
         return True
 
@@ -691,7 +729,8 @@ class StreamingClusterEngine:
         dirty_captured = self.tree.dirty_mass
         n_points = self.tree.n_points
         src = self._table if self._table.ready else self._host_table
-        cap = src.capture(n_points)
+        with span("offline.capture", self.span_s):
+            cap = src.capture(n_points)
         if self.async_offline:
             self._inflight_consumed = dirty_captured
             th = threading.Thread(
@@ -730,14 +769,19 @@ class StreamingClusterEngine:
         transfer; either routes the O(L²) stage through the mesh-sharded
         shard_map path when the engine opted in — and the result
         publishes as ONE snapshot."""
-        t0 = time.perf_counter()
-        res, rep, n_b, center = capture.recluster(
-            self.backend, min_pts=self.min_pts,
-            min_cluster_size=self.min_cluster_size,
-            mesh=self.mesh, mesh_axis=self.mesh_axis,
-        )
-        return self._publish_snapshot(
-            res, rep, n_b, center, n_points, dirty_captured, t0)
+        with span("offline.pass", self.span_s):
+            t0 = time.perf_counter()
+            res, rep, n_b, center = capture.recluster(
+                self.backend, min_pts=self.min_pts,
+                min_cluster_size=self.min_cluster_size,
+                mesh=self.mesh, mesh_axis=self.mesh_axis,
+            )
+            snap = self._publish_snapshot(
+                res, rep, n_b, center, n_points, dirty_captured, t0)
+        if res.program_s is not None:
+            self.span_s["offline.program"] = (
+                self.span_s.get("offline.program", 0.0) + res.program_s)
+        return snap
 
     def _publish_snapshot(self, res, rep, n_b, center, n_points,
                           dirty_captured, t0):
@@ -788,6 +832,7 @@ class StreamingClusterEngine:
             self._offline_thread.join()
             self._offline_thread = None
         self._settle()
+        self._mirror_counters()
         self._raise_pending_offline_error()
 
     # -- checkpointing (DESIGN.md §11: snapshot shipping & recovery) -------
