@@ -73,10 +73,12 @@ class BubbleTree:
         # reproduce from the block's own scatter; it patches these rows.
         self._struct_dirty: set[int] = set()
         # structural changes made (Algorithm 1's steps and the block
-        # paths' dissolves), and seconds inside each block-path span
-        # (repro.spans): counters the engine mirrors into its stats
+        # paths' dissolves), internal CFs the block paths recomputed, and
+        # seconds inside each block-path span (repro.spans): counters the
+        # engine mirrors into its stats
         self.splits = 0
         self.dissolves = 0
+        self.cf_refreshes = 0
         self.span_s: dict[str, float] = {}
 
         # --- node SoA ---
@@ -318,7 +320,7 @@ class BubbleTree:
         """Bulk bookkeeping for a block whose point→leaf assignment was
         already computed (host argmin above, or the device flat path,
         core.bubble_flat): allocate pids, extend membership grouped per
-        touched leaf, ONE CF update per leaf + ancestor rebuild, then
+        touched leaf, ONE CF update per leaf + ancestor refresh, then
         block maintenance to fixpoint.  ``overfull_hint`` is the device
         work-list (leaf ids the scatter saw cross ``leaf_cap``) — when it
         is provided, empty, and the leaf count already matches target,
@@ -346,7 +348,7 @@ class BubbleTree:
             for leaf, cnt in zip(uniq, counts):
                 self.leaf_points[int(leaf)].extend(sorted_pids[off : off + cnt].tolist())
                 off += int(cnt)
-            self._recompute_internal_cfs()
+            self._refresh_ancestor_cfs(uniq)
         self.n_points += n
         self.dirty_mass += float(n)
         self.mutations += 1
@@ -363,7 +365,7 @@ class BubbleTree:
     def delete_block(self, pids):
         """Throughput path for deletions, mirroring insert_block: group the
         victims per leaf, retire them with ONE CF subtraction per touched
-        leaf, rebuild ancestor CFs bottom-up, then dissolve underfilled
+        leaf, refresh ancestor CFs bottom-up, then dissolve underfilled
         leaves and run the maintenance deficit loop.  CF additivity makes
         the resulting statistics identical to repeated delete() — only the
         maintenance schedule differs."""
@@ -395,7 +397,7 @@ class BubbleTree:
                 for pid in victims:
                     self.point_leaf[pid] = -1
                     self._point_free.append(pid)
-            self._recompute_internal_cfs()
+            self._refresh_ancestor_cfs(np.fromiter(by_leaf, dtype=np.int64, count=len(by_leaf)))
         self.n_points -= len(pids)
         self.dirty_mass += float(len(pids))
         self.mutations += 1
@@ -434,6 +436,28 @@ class BubbleTree:
             self.SS[nid] += dSS
             self.N[nid] += dN
             nid = int(self.parent[nid])
+
+    def _refresh_ancestor_cfs(self, leaves: np.ndarray):
+        """Recompute the CF of every internal ancestor of ``leaves`` as the
+        fresh sum of its children, bottom-up one tree level at a time, with
+        one segment reduction per statistic per level.  Fresh sums, not the
+        block's deltas, so rounding cannot drift over a long stream; every
+        other internal CF already equals its children's sum, which each
+        structural path keeps through ``_cf_update_path``."""
+        # -1 (the root's parent, dead nodes) reads mark's last slot: False
+        par = np.where(self.node_alive, self.parent, -1)
+        mark = np.zeros(par.shape[0] + 1, dtype=bool)
+        nodes = leaves
+        while (nodes := np.setdiff1d(par[nodes], -1)).size:
+            mark[nodes] = True
+            kids = np.nonzero(mark[par])[0]
+            mark[nodes] = False
+            kids = kids[np.argsort(par[kids])]
+            starts = np.flatnonzero(np.diff(par[kids], prepend=-1))
+            self.LS[nodes] = np.add.reduceat(self.LS[kids], starts, axis=0)
+            self.SS[nodes] = np.add.reduceat(self.SS[kids], starts)
+            self.N[nodes] = np.add.reduceat(self.N[kids], starts)
+            self.cf_refreshes += int(nodes.size)
 
     def _insert_point_into_tree(self, pid: int):
         p = self.PX[pid]
@@ -708,15 +732,6 @@ class BubbleTree:
     # ------------------------------------------------------------------
     # consistency checking (tests)
     # ------------------------------------------------------------------
-
-    def _recompute_internal_cfs(self):
-        order = np.nonzero(self.node_alive & ~self.is_leaf)[0]
-        order = order[np.argsort(self.height[order])]
-        for nid in order:
-            ids = np.asarray(self.children[nid], dtype=np.int64)
-            self.LS[nid] = self.LS[ids].sum(axis=0)
-            self.SS[nid] = float(self.SS[ids].sum())
-            self.N[nid] = float(self.N[ids].sum())
 
     def check_invariants(self):
         assert self.node_alive[self.root]

@@ -418,6 +418,7 @@ class StreamingClusterEngine:
             # join (`span_s` as a fresh dict: a copy of stats keeps its reading)
             "tree_splits": 0,
             "tree_dissolves": 0,
+            "tree_cf_refreshes": 0,
             "span_s": {},
             # host clock and the ingest thread's CPU time around poll's
             # block-apply loop (the ε-pass excluded): their ratio says
@@ -520,6 +521,7 @@ class StreamingClusterEngine:
         """Copy the tree's counters and the span seconds into the stats."""
         self.stats["tree_splits"] = self.tree.splits
         self.stats["tree_dissolves"] = self.tree.dissolves
+        self.stats["tree_cf_refreshes"] = self.tree.cf_refreshes
         self.stats["span_s"] = dict(self.span_s)
 
     @staticmethod

@@ -1,5 +1,7 @@
 """Bubble-tree (paper §4.1, Algorithm 1) structural + behavioral tests."""
 
+import math
+
 import numpy as np
 import pytest
 from _hypothesis_compat import given, settings, st
@@ -118,6 +120,45 @@ class TestBlockOps:
         bt.delete_block(ids[:80])
         assert bt.n_points == 120
         bt.check_invariants()
+
+    def test_block_paths_keep_internal_cfs_fresh(self, rng):
+        """Sliding stream off the origin, with a concentrated block that
+        forces splits and its expiry that forces dissolves: after every
+        block each alive internal node's CF is the sum of its children's,
+        to 1e-12 of the summed magnitudes (exact sums taken here)."""
+        d = 4
+        bt = BubbleTree(dim=d, compression=0.05)
+        fifo = bt.insert_block(rng.normal(size=(3000, d)) + 5.0)
+
+        def check():
+            bt.check_invariants()
+            for nid in np.nonzero(bt.node_alive & ~bt.is_leaf)[0]:
+                kids = [int(k) for k in bt.children[int(nid)]]
+                for j in range(d):
+                    col = [float(bt.LS[k, j]) for k in kids]
+                    scale = math.fsum(abs(x) for x in col)
+                    assert abs(bt.LS[nid, j] - math.fsum(col)) <= 1e-12 * scale, (nid, j)
+                ss = math.fsum(float(bt.SS[k]) for k in kids)
+                assert abs(bt.SS[nid] - ss) <= 1e-12 * ss, nid
+                assert bt.N[nid] == sum(float(bt.N[k]) for k in kids), nid
+
+        hot = None
+        for step in range(60):
+            if step == 20:
+                s0 = bt.splits
+                hot = bt.insert_block(rng.normal(size=(400, d)) * 0.01 + 7.0)
+                assert bt.splits > s0
+                check()
+            if step == 40:
+                d0 = bt.dissolves
+                bt.delete_block(hot)
+                assert bt.dissolves > d0
+                check()
+            fifo += bt.insert_block(rng.normal(size=(100, d)) + 5.0)
+            check()
+            bt.delete_block(fifo[:100])
+            fifo = fifo[100:]
+            check()
 
 
 class TestMaintenanceFixpoint:

@@ -36,6 +36,17 @@ def _host_spans(trace_dir) -> dict:
     return out
 
 
+def _ancestors(tree, leaves) -> int:
+    """Distinct internal ancestors of ``leaves``, up the parent links."""
+    seen = set()
+    for nid in {int(x) for x in leaves}:
+        nid = int(tree.parent[nid])
+        while nid != -1 and nid not in seen:
+            seen.add(nid)
+            nid = int(tree.parent[nid])
+    return len(seen)
+
+
 def _session(trace_dir):
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
@@ -46,7 +57,9 @@ def _session(trace_dir):
 @pytest.fixture(scope="module")
 def traced(tmp_path_factory):
     """Fill outside any session, then slide the window, query and flush
-    inside one; the tree's split/dissolve calls are counted by spies."""
+    inside one; the tree's split/dissolve calls are counted by spies, and
+    each block path's CF refreshes are recorded beside the ancestors of
+    the leaves it touched."""
     rng = np.random.default_rng(0)
     eng = StreamingClusterEngine(DIM, min_pts=5, compression=0.05, epsilon=0.1,
                                  backend="jnp", device_assign=True)
@@ -63,7 +76,22 @@ def traced(tmp_path_factory):
         made["dissolves"] += 1
         return dissolve(leaf)
 
+    apply, delete = tree.apply_assigned_block, tree.delete_block
+    refreshes = {"insert": [], "delete": []}
+
+    def apply_spy(X, leaf_per_row, overfull_hint=None):
+        want, r0 = _ancestors(tree, leaf_per_row), tree.cf_refreshes
+        pids = apply(X, leaf_per_row, overfull_hint)
+        refreshes["insert"].append((want, tree.cf_refreshes - r0))
+        return pids
+
+    def delete_spy(pids):
+        want, r0 = _ancestors(tree, tree.point_leaf[np.asarray(pids)]), tree.cf_refreshes
+        delete(pids)
+        refreshes["delete"].append((want, tree.cf_refreshes - r0))
+
     tree._split_leaf, tree._dissolve_leaf = split_spy, dissolve_spy
+    tree.apply_assigned_block, tree.delete_block = apply_spy, delete_spy
     pids = list(eng.ingest(rng.normal(size=(2000, DIM))))  # outside the session
     before = dict(eng.stats)
     trace_dir = tmp_path_factory.mktemp("trace")
@@ -81,7 +109,7 @@ def traced(tmp_path_factory):
     finally:
         jax.profiler.stop_trace()
     return {"eng": eng, "before": before, "spans": _host_spans(trace_dir), "polls": polls,
-            "made": made, "result": result}
+            "made": made, "refreshes": refreshes, "result": result}
 
 
 @pytest.mark.parametrize("name", SPANS)
@@ -122,6 +150,17 @@ def test_tree_counters_match_the_tree(traced):
     assert eng.tree.dissolves == made["dissolves"]
     assert eng.stats["tree_splits"] == made["splits"]
     assert eng.stats["tree_dissolves"] == made["dissolves"]
+
+
+@pytest.mark.parametrize("path", ["insert", "delete"])
+def test_cf_refreshes_count_the_touched_leaves_ancestors(traced, path):
+    """Each block refreshes exactly the distinct internal ancestors of the
+    leaves it touched, and poll mirrors the tree's counter."""
+    eng, calls = traced["eng"], traced["refreshes"]
+    assert calls[path] and all(got == want > 0 for want, got in calls[path])
+    total = sum(got for c in calls.values() for _, got in c)
+    assert eng.tree.cf_refreshes == total
+    assert eng.stats["tree_cf_refreshes"] == total
 
 
 def test_ingest_wall_and_cpu_counters_grow(traced):
