@@ -357,7 +357,8 @@ def _expect_impl(cfg, stats, kernel):
         "offline_impl": (
             {"core_distances": kernel, "mutual_reach": kernel, "mst": "boruvka-dense"}
             if dense else
-            {"core_distances": "grid", "mutual_reach": "none", "mst": "boruvka-grid"}
+            {"core_distances": "grid", "mutual_reach": "none",
+             "mst": f"boruvka-strips-{kernel}"}
         ),
     }
     got = {k: stats[k] for k in want}

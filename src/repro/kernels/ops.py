@@ -324,8 +324,8 @@ def offline_stages(Lp: int, use_ref: bool, spatial: bool, mesh=None,
     if spatial:
         return {
             "core_distances": "grid",
-            "mutual_reach": "jnp" if with_w else "none",
-            "mst": "boruvka-grid",
+            "mutual_reach": kernel_impl(use_ref) if with_w else "none",
+            "mst": f"boruvka-strips-{kernel_impl(use_ref)}",
         }
     return {
         "core_distances": bcd_impl(Lp, use_ref),
@@ -565,7 +565,7 @@ def _offline_pipeline(
     op-name metadata only, so a device trace can sum the time of each
     stage while the HLO instruction names stay as they were."""
     from repro.core.hierarchy_jax import hierarchy_fixed
-    from repro.core.mst import boruvka_grid_jax, boruvka_jax
+    from repro.core.mst import boruvka_jax, boruvka_strips_jax
 
     iota = jnp.arange(rep.shape[0])
     is_pad = iota >= n_valid
@@ -574,21 +574,23 @@ def _offline_pipeline(
         eu, ev, ew, valid = _sharded_mst_stage(
             rep, n_b, extent, n_valid, min_pts, mesh, mesh_axis, spatial)
     elif spatial:
-        # grid-pruned sub-quadratic pass (kernels.grid): cd and the MST
-        # come from tile-pruned exact searches and carry jnp-reference
-        # bits on BOTH backends; the (Lp, Lp) matrix is only assembled
-        # when a caller asked for it (return_w) — skipping it is where
-        # the quadratic memory/compute goes away
+        # the pass that never holds the (Lp, Lp) matrix: core distances
+        # from the grid's tile-pruned exact search (jnp-reference bits on
+        # both backends), then Borůvka over row strips of the backend's
+        # mutual-reachability kernel (`boruvka_strips_jax`); the matrix is
+        # only assembled when a caller asked for it (return_w), from the
+        # same kernel, so the MST is exact against the W it returns
         from repro.kernels.grid import build_grid, grid_core_distances
 
         with jax.named_scope("core_distance"):
             grid = build_grid(rep, ~is_pad)
             cd = grid_core_distances(grid, n_b, extent, min_pts, rep.shape[1])
         with jax.named_scope("mst"):
-            eu, ev, ew, valid = boruvka_grid_jax(grid, cd)
+            eu, ev, ew, valid, out["mst_rounds"], out["mst_strip_calls"] = (
+                boruvka_strips_jax(rep, cd, is_pad, use_ref=use_ref))
         if with_w:
             with jax.named_scope("mutual_reach"):
-                W = mutual_reachability(rep, rep, cd, cd, zero_diag=True, use_ref=True)
+                W = mutual_reachability(rep, rep, cd, cd, zero_diag=True, use_ref=use_ref)
                 out["W"] = jnp.where(is_pad[:, None] | is_pad[None, :], jnp.inf, W)
     else:
         # bubble_mutual_reachability's two steps, each under its own scope
@@ -648,6 +650,10 @@ class OfflineClusterResult:
     # seconds of the "offline.program" span: the device program's call
     # through its result fetch (None on paths that have no such span)
     program_s: float | None = None
+    # rounds the strip Borůvka ran and the strip kernel calls it made
+    # (0 on passes that ran another MST engine)
+    mst_rounds: int = 0
+    mst_strip_calls: int = 0
 
     @property
     def n_clusters(self) -> int:
@@ -808,6 +814,8 @@ def _unwrap_result(out, L: int, mcs: float, weights: np.ndarray) -> OfflineClust
         cluster_weight=out["cluster_weight"].astype(np.float64)[:K],
         selected=sel,
         all_stabilities=all_stab,
+        mst_rounds=int(out.get("mst_rounds", 0)),
+        mst_strip_calls=int(out.get("mst_strip_calls", 0)),
     )
 
 
@@ -1058,11 +1066,12 @@ class ClusterBackend:
         still XLA-compiled, just without the hand-tiled kernels),
       * ``auto``   — pallas on TPU, jnp elsewhere.
 
-    ``spatial_index=True`` additionally routes the three O(L²) hot
-    paths — Eq. 6 core distances, Borůvka candidate edges, and batched
-    assignment — through the grid-pruned exact engine (kernels.grid,
-    DESIGN.md §10).  The grid layer itself is backend-independent jnp;
-    the flag composes with either backend name.
+    ``spatial_index=True`` additionally routes Eq. 6 core distances and
+    batched assignment through the grid-pruned exact engine (kernels.grid,
+    DESIGN.md §10), and Borůvka through row strips of this backend's
+    mutual-reachability kernel (core.mst.boruvka_strips_jax), so no
+    (L, L) matrix is held.  The grid layer itself is backend-independent
+    jnp; the flag composes with either backend name.
     """
 
     _ALIASES = {"ref": "jnp", "cpu": "jnp", "tpu": "pallas"}

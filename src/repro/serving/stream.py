@@ -230,10 +230,13 @@ class StreamingClusterEngine:
         the population changed since the last pass.
       max_block: scheduler block cap (requests coalesced per apply).
       backend: 'auto' | 'pallas' | 'jnp' — resolved once, see ops.get_backend.
-      spatial_index: route core distances, Borůvka candidate generation and
-        query/ingest assignment through the grid-pruned neighbor engine
-        (kernels.grid).  Bit-exact against the dense paths; opt-in because
-        the win only shows at serving-scale L.
+      spatial_index: run the ε-pass without the (Lp, Lp) matrix — core
+        distances and query/ingest assignment through the grid-pruned
+        neighbor engine (kernels.grid), Borůvka over row strips of the
+        mutual-reachability kernel (core.mst.boruvka_strips_jax) — for L
+        whose dense matrix would not fit the device.  Bit-exact against the
+        dense paths given the same core distances; opt-in because the win
+        only shows at serving-scale L.
       async_offline: run offline passes in a background thread; `query`
         keeps serving the previous snapshot meanwhile.
       device_assign: route the online point→leaf argmin through the kernel
@@ -405,6 +408,10 @@ class StreamingClusterEngine:
             "deletes": 0,
             "blocks_applied": 0,
             "recluster_count": 0,
+            # rounds and strip kernel calls of the strip Borůvka, summed
+            # over the passes (`OfflineClusterResult.mst_rounds` etc.)
+            "mst_rounds_total": 0,
+            "mst_strip_calls_total": 0,
             "recluster_skipped_busy": 0,
             "recluster_failures": 0,
             "offline_seconds_total": 0.0,
@@ -812,6 +819,8 @@ class StreamingClusterEngine:
             )
             self._snapshot = snap
         self.stats["recluster_count"] += 1
+        self.stats["mst_rounds_total"] += res.mst_rounds
+        self.stats["mst_strip_calls_total"] += res.mst_strip_calls
         self.stats["offline_seconds_total"] += wall
         self.stats["offline_impl"] = dict(res.stages)
         return snap

@@ -62,7 +62,7 @@ def test_phase_grid_tiny(smoke):
     lines = []
     rep = smoke.run_phase(_tiny(smoke.PHASE_B), backend="jnp", kernel="jnp",
                           emit=lines.append)
-    assert rep["impl"]["offline_impl"]["mst"] == "boruvka-grid"
+    assert rep["impl"]["offline_impl"]["mst"] == "boruvka-strips-jnp"
     assert rep["nmi"] >= 0.9
 
 

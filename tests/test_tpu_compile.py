@@ -100,8 +100,9 @@ def test_mutual_reachability(compiled_kernels):
 
 @pytest.mark.parametrize("stage", ["core_distances", "mst"])
 def test_grid_pass_32k(compiled_kernels, stage):
-    """The grid-pruned pass at Lp = 32768, where a dense d_m is 4 GiB."""
-    from repro.core.mst import boruvka_grid_jax
+    """The spatial pass at Lp = 32768, where a dense d_m is 4 GiB: grid
+    core distances, and Borůvka over row strips of the Mosaic kernel."""
+    from repro.core.mst import boruvka_strips_jax
     from repro.kernels.grid import build_grid, grid_core_distances
 
     S = compiled_kernels
@@ -111,12 +112,26 @@ def test_grid_pass_32k(compiled_kernels, stage):
         return grid_core_distances(build_grid(rep, jnp.arange(Lp) < n), n_b, extent, 15, D)
 
     def mst(rep, cd, n):
-        return boruvka_grid_jax(build_grid(rep, jnp.arange(Lp) < n), cd)
+        return boruvka_strips_jax(rep, cd, jnp.arange(Lp) >= n, use_ref=False)
 
     if stage == "core_distances":
         _compile(cd, S((Lp, D)), S((Lp,)), S((Lp,)), S((), jnp.int32))
     else:
-        _compile(mst, S((Lp, D)), S((Lp,)), S((), jnp.int32))
+        hlo = _compile(mst, S((Lp, D)), S((Lp,)), S((), jnp.int32))
+        assert "tpu_custom_call" in hlo
+
+
+def test_device_table_pipeline_32k(compiled_kernels):
+    """The whole device-online spatial ε-pass at Lp = 32768 fits one chip."""
+    S = compiled_kernels
+    Lp = 32768
+    hlo = _compile(
+        functools.partial(ops._device_table_pipeline, min_pts=15, use_ref=False,
+                          rows=Lp, spatial=True),
+        S((2 * Lp, D)), S((2 * Lp, D)), S((2 * Lp,)), S((2 * Lp,)), S((2 * Lp,)),
+        S((2 * Lp,), jnp.bool_), S(()),
+    )
+    assert "tpu_custom_call" in hlo
 
 
 def test_offline_pipeline_8k(compiled_kernels):
