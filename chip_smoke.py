@@ -355,10 +355,11 @@ def _expect_impl(cfg, stats, kernel):
         "ingest_impl": kernel if dense else "grid",
         "query_impl": kernel if dense else "grid",
         "offline_impl": (
-            {"core_distances": kernel, "mutual_reach": kernel, "mst": "boruvka-dense"}
+            {"core_distances": kernel, "mutual_reach": kernel, "mst": "boruvka-dense",
+             "hierarchy": kernel}
             if dense else
             {"core_distances": "grid", "mutual_reach": "none",
-             "mst": f"boruvka-strips-{kernel}"}
+             "mst": f"boruvka-strips-{kernel}", "hierarchy": kernel}
         ),
     }
     got = {k: stats[k] for k in want}

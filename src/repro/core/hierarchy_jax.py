@@ -30,12 +30,16 @@ They are a *relabeling* of the oracle's ``n, n+1, …`` convention; parity
 tests compare up to permutation.
 
 Sequential-but-on-device is the point: union-find single-linkage, the
-condense DFS, and bottom-up EOM are O(Lp) `lax.scan`s (unroll=2 — the
-measured CPU sweet spot between while-loop dispatch overhead and compile
-time), while selection blocking and label resolution collapse to
-O(log Lp) pointer-doubling sweeps.  All of it is tiny next to the
-O(Lp²) d_m/Borůvka stages it fuses with, and it eliminates the per-pass
-host sync + interpreted Python of the old path.
+condense DFS, and bottom-up EOM are O(Lp) sweeps, while selection
+blocking and label resolution collapse to O(log Lp) pointer-doubling
+sweeps.  Each sequential sweep has two implementations, named by
+``impl`` (`kernels.ops.hierarchy_impl` chooses): ``"jnp"``, the
+`lax.scan`s below (unroll=2 — the measured CPU sweet spot between
+while-loop dispatch overhead and compile time), which stay the
+reference; and the Pallas kernels of `kernels.hierarchy`, which run each
+sweep on the TPU's scalar unit with its state in SMEM.  As XLA while
+loops the three sweeps cost 6–10 µs a step on a v5e, most of a dense
+ε-pass; both return the same bits.
 """
 
 from __future__ import annotations
@@ -116,7 +120,8 @@ class ExtractionArrays(NamedTuple):
 # step 4: single-linkage dendrogram from fixed-size MST buffers
 # --------------------------------------------------------------------------
 
-def single_linkage_fixed(eu, ev, ew, valid, n_valid, weights) -> SingleLinkageArrays:
+def single_linkage_fixed(eu, ev, ew, valid, n_valid, weights,
+                         impl: str = "jnp") -> SingleLinkageArrays:
     """Edge-sorted union-find single-linkage over padded edge buffers.
 
     Args:
@@ -125,6 +130,9 @@ def single_linkage_fixed(eu, ev, ew, valid, n_valid, weights) -> SingleLinkageAr
         for a connected valid block.
       n_valid: () int — real leaf count L; leaves ≥ L are padding.
       weights: (Lp,) f32 leaf weights (pad rows 0).
+      impl: ``"jnp"`` runs the sweep as the scan below (the reference);
+        ``"pallas"`` / ``"pallas-interpret"`` as the union-find kernel
+        of `kernels.hierarchy`, compiled or interpreted.
 
     Union-find is component *relabeling* (O(Lp) vectorized `where` per
     merge) rather than pointer chasing: each merge relabels the absorbed
@@ -155,6 +163,14 @@ def single_linkage_fixed(eu, ev, ew, valid, n_valid, weights) -> SingleLinkageAr
 
     order = jnp.argsort(w_e, stable=True)
     u_s, v_s, w_s = u_e[order], v_e[order], w_e[order]
+    if impl != "jnp":
+        from repro.kernels.hierarchy import single_linkage_sweep
+
+        ml, mr, md, mw, trash_w = single_linkage_sweep(
+            u_s, v_s, w_s, weights, interpret=impl == "pallas-interpret")
+        # internal node Lp + k weighs mw[k] (0 where the merge was rejected)
+        node_weight = jnp.concatenate([weights, mw[:M], trash_w[None]])
+        return SingleLinkageArrays(ml[:M], mr[:M], md[:M], mw[:M], node_weight)
 
     comp0 = jnp.arange(Lp, dtype=jnp.int32)
     node_of_comp0 = jnp.concatenate(
@@ -203,7 +219,8 @@ def single_linkage_fixed(eu, ev, ew, valid, n_valid, weights) -> SingleLinkageAr
 # step 5a: condensed tree (array-form DFS, top-down over node ids)
 # --------------------------------------------------------------------------
 
-def condense_fixed(slt: SingleLinkageArrays, weights, min_cluster_size) -> CondensedArrays:
+def condense_fixed(slt: SingleLinkageArrays, weights, min_cluster_size,
+                   impl: str = "jnp") -> CondensedArrays:
     """Collapse the dendrogram exactly like ``hdbscan.condense_tree``:
 
     a split spawns two new condensed clusters only when both sides are
@@ -225,6 +242,8 @@ def condense_fixed(slt: SingleLinkageArrays, weights, min_cluster_size) -> Conde
     lam_of = jnp.where(
         slt.dist > 0.0, jnp.minimum(1.0 / slt.dist, MAX_LAMBDA), MAX_LAMBDA
     ).astype(jnp.float32)
+    if impl != "jnp":
+        return _condense_kernel_path(slt, weights, mcs, lam_of, impl)
 
     cl0 = jnp.zeros((n_nodes + 1,), jnp.int32)  # root enters cluster 0
     lam0 = jnp.zeros((n_nodes + 1,), jnp.float32)
@@ -281,6 +300,58 @@ def condense_fixed(slt: SingleLinkageArrays, weights, min_cluster_size) -> Conde
     )
 
 
+def _condense_kernel_path(slt, weights, mcs, lam_of, impl) -> CondensedArrays:
+    """`condense_fixed` through the condense kernel: the sweep gives each
+    merge row its children's labels, their entry λ and its own label; the
+    leaf rows and the cluster table are scattered from those (every node
+    is the child of one merge and every label is born once, so the
+    scatters' indices are unique and the bits are the scan's)."""
+    from repro.kernels.hierarchy import condense_sweep
+
+    M = slt.left.shape[0]
+    Lp = M + 1
+    C = 2 * Lp
+    left, right = slt.left, slt.right
+    wl, wr = slt.node_weight[left], slt.node_weight[right]
+    heavy = (((wl >= mcs) & (left >= Lp)).astype(jnp.int32)
+             | (((wr >= mcs) & (right >= Lp)).astype(jnp.int32) << 1))
+
+    def pad(a):  # the kernel's streams are Lp rows; the last is never read
+        return jnp.concatenate([a, jnp.zeros((1,), a.dtype)])
+
+    lab_l, lab_r, clam, par, n_labels = condense_sweep(
+        pad(left), pad(right), pad(lam_of), pad(heavy),
+        interpret=impl == "pallas-interpret")
+    lab_l, lab_r, clam, par = lab_l[:M], lab_r[:M], clam[:M], par[:M]
+
+    # leaves: out-of-range indices (internal children, trash) are dropped
+    leaf_l = jnp.where(left < Lp, left, Lp)
+    leaf_r = jnp.where(right < Lp, right, Lp)
+    point_parent = (jnp.zeros((Lp,), jnp.int32)
+                    .at[leaf_l].set(lab_l, mode="drop").at[leaf_r].set(lab_r, mode="drop"))
+    point_lambda = (jnp.zeros((Lp,), jnp.float32)
+                    .at[leaf_l].set(clam, mode="drop").at[leaf_r].set(clam, mode="drop"))
+    # clusters: a merge whose left label is not its own founds two
+    born = lab_l != par
+    sa = jnp.where(born, lab_l, C + 1)
+    sb = jnp.where(born, lab_r, C + 1)
+    cp = (jnp.full((C + 1,), C, jnp.int32)
+          .at[sa].set(par, mode="drop").at[sb].set(par, mode="drop"))
+    cb = (jnp.zeros((C + 1,), jnp.float32)
+          .at[sa].set(lam_of, mode="drop").at[sb].set(lam_of, mode="drop"))
+    cw = (jnp.zeros((C + 1,), jnp.float32).at[0].set(slt.node_weight[2 * Lp - 2])
+          .at[sa].set(wl, mode="drop").at[sb].set(wr, mode="drop"))
+    return CondensedArrays(
+        point_parent=point_parent,
+        point_lambda=point_lambda,
+        point_weight=weights,
+        cluster_parent=cp,
+        cluster_birth=cb,
+        cluster_weight=cw,
+        n_labels=n_labels,
+    )
+
+
 # --------------------------------------------------------------------------
 # step 5b: stabilities + flat extraction + label resolution
 # --------------------------------------------------------------------------
@@ -289,6 +360,7 @@ def extract_fixed(
     ct: CondensedArrays,
     method: str = "eom",
     allow_single_cluster: bool = False,
+    impl: str = "jnp",
 ) -> ExtractionArrays:
     """Excess-of-mass (or leaf) extraction over the array condensed tree.
 
@@ -330,12 +402,22 @@ def extract_fixed(
         p = jnp.where(live & (c >= 1), ct.cluster_parent[c], trash)
         return (acc.at[p].add(sub), kids.at[p].add(1), sel), None
 
-    acc0 = jnp.zeros((C + 1,), jnp.float32)
-    kids0 = jnp.zeros((C + 1,), jnp.int32)
-    sel0 = jnp.zeros((C + 1,), bool)
-    (_, kid_count, sel), _ = jax.lax.scan(
-        eom_body, (acc0, kids0, sel0), jnp.arange(C), unroll=2
-    )
+    if impl == "jnp":
+        acc0 = jnp.zeros((C + 1,), jnp.float32)
+        kids0 = jnp.zeros((C + 1,), jnp.int32)
+        sel0 = jnp.zeros((C + 1,), bool)
+        (_, kid_count, sel), _ = jax.lax.scan(
+            eom_body, (acc0, kids0, sel0), jnp.arange(C), unroll=2
+        )
+    else:
+        # the kernel walks only the live labels; child counts need no sweep
+        from repro.kernels.hierarchy import eom_sweep
+
+        kid_of = jnp.where(row_mask[:C], ct.cluster_parent[:C], trash)
+        kid_count = jnp.zeros((C + 1,), jnp.int32).at[kid_of].add(1)
+        flag = eom_sweep(stab[:C], ct.cluster_parent[:C], kid_count[:C], ct.n_labels,
+                         interpret=impl == "pallas-interpret")
+        sel = jnp.concatenate([(flag != 0) & in_use[:C], jnp.zeros((1,), bool)])
 
     # pointer-doubling setup: the label tree is ≤ C deep but log₂(C)
     # doubling steps traverse any ancestor chain
@@ -383,22 +465,26 @@ def extract_fixed(
 
 
 # trace-contract: hierarchy_fixed rules=f32,no-callbacks,pow2
-@functools.partial(jax.jit, static_argnames=("method", "allow_single_cluster"))
+@functools.partial(jax.jit, static_argnames=("method", "allow_single_cluster", "impl"))
 def hierarchy_fixed(
     eu, ev, ew, valid, n_valid, weights, min_cluster_size,
     method: str = "eom",
     allow_single_cluster: bool = False,
+    impl: str = "jnp",
 ):
     """MST buffers → (SingleLinkageArrays, CondensedArrays, ExtractionArrays).
 
     The fully fused device path, shape-static in Lp.  jit'd here so eager
     callers (tests, notebooks) hit the per-bucket compile cache instead
     of re-tracing the scans each call; inside `kernels.ops`'s fused
-    pipeline the jit nests and inlines.
+    pipeline the jit nests and inlines.  ``impl`` names what runs the
+    three sweeps (`kernels.ops.hierarchy_impl`); every choice returns the
+    same bits.
     """
-    slt = single_linkage_fixed(eu, ev, ew, valid, n_valid, weights)
-    ct = condense_fixed(slt, jnp.asarray(weights, jnp.float32), min_cluster_size)
-    ex = extract_fixed(ct, method=method, allow_single_cluster=allow_single_cluster)
+    slt = single_linkage_fixed(eu, ev, ew, valid, n_valid, weights, impl=impl)
+    ct = condense_fixed(slt, jnp.asarray(weights, jnp.float32), min_cluster_size, impl=impl)
+    ex = extract_fixed(ct, method=method, allow_single_cluster=allow_single_cluster,
+                       impl=impl)
     return slt, ct, ex
 
 
@@ -406,9 +492,10 @@ def hierarchy_fixed(
 # explicit-edge-list convenience (property tests / oracle comparisons)
 # --------------------------------------------------------------------------
 
-@functools.partial(jax.jit, static_argnums=(4,))
-def _sl_fixed_jit(eu, ev, ew, valid, n, weights):
-    return single_linkage_fixed(eu, ev, ew, valid, jnp.asarray(n, jnp.int32), weights)
+@functools.partial(jax.jit, static_argnums=(4, 6))
+def _sl_fixed_jit(eu, ev, ew, valid, n, weights, impl):
+    return single_linkage_fixed(
+        eu, ev, ew, valid, jnp.asarray(n, jnp.int32), weights, impl=impl)
 
 
 def single_linkage_jax(u, v, w, n: int, weights=None):
@@ -417,7 +504,11 @@ def single_linkage_jax(u, v, w, n: int, weights=None):
     the fixed kernel, and returns the ``n - 1`` real merge records as
     host numpy ``(left, right, dist, weight)`` — pad merges (attached at
     PAD_DIST) are sliced away, exactly the rows the oracle produces.
+    The sweep runs where `kernels.ops.hierarchy_impl` puts it under the
+    ``REPRO_FORCE_REF`` policy, as every kernel wrapper's default does.
     """
+    from repro.kernels import ops
+
     u = np.asarray(u, dtype=np.int64)
     v = np.asarray(v, dtype=np.int64)
     w = np.asarray(w, dtype=np.float64)
@@ -439,7 +530,7 @@ def single_linkage_jax(u, v, w, n: int, weights=None):
     wpad[:n] = weights
     slt = _sl_fixed_jit(
         jnp.asarray(eu), jnp.asarray(ev), jnp.asarray(ew), jnp.asarray(valid),
-        int(n), jnp.asarray(wpad),
+        int(n), jnp.asarray(wpad), ops.hierarchy_impl(Lp, ops._resolve_ref(None)),
     )
     keep = np.asarray(slt.dist) < PAD_DIST
     # real merges are the first n-1 in sorted order (pads sort above

@@ -31,6 +31,7 @@ from repro.spans import span
 
 from . import assign as _assign_k
 from . import bubble_cd as _bcd_k
+from . import hierarchy as _hier_k
 from . import knn as _knn_k
 from . import mutual_reach as _mr_k
 from . import pairwise as _pw_k
@@ -309,28 +310,41 @@ def bubble_core_distances(
     return cd[:L]
 
 
+def hierarchy_impl(Lp: int, use_ref: bool, mesh=None) -> str:
+    """What runs the hierarchy's three sweeps over an Lp-leaf bucket
+    (`kernel_impl` names): the SMEM kernels of `kernels.hierarchy` where
+    their resident state fits (Lp ≤ ``SMEM_ROWS``), else the jnp scans.
+    The mesh pass keeps the scans: its hierarchy runs replicated under
+    the GSPMD jit, which a Mosaic call does not partition."""
+    return kernel_impl(use_ref or mesh is not None or Lp > _hier_k.SMEM_ROWS)
+
+
 def offline_stages(Lp: int, use_ref: bool, spatial: bool, mesh=None,
                    with_w: bool = True) -> dict[str, str]:
-    """What serves each O(L²) stage of `_offline_pipeline` over an Lp-row
+    """What serves each stage of `_offline_pipeline` over an Lp-row
     table — the choices the pipeline makes while it traces, named so the
     engine can record them (``kernel_impl`` names)."""
+    hierarchy = hierarchy_impl(Lp, use_ref, mesh)
     if mesh is not None:
         kind = "grid" if spatial else "jnp"
         return {
             "core_distances": f"{kind}-sharded",
             "mutual_reach": "none" if spatial else "jnp-sharded",
             "mst": f"boruvka-{kind}-sharded",
+            "hierarchy": hierarchy,
         }
     if spatial:
         return {
             "core_distances": "grid",
             "mutual_reach": kernel_impl(use_ref) if with_w else "none",
             "mst": f"boruvka-strips-{kernel_impl(use_ref)}",
+            "hierarchy": hierarchy,
         }
     return {
         "core_distances": bcd_impl(Lp, use_ref),
         "mutual_reach": kernel_impl(use_ref),
         "mst": "boruvka-dense",
+        "hierarchy": hierarchy,
     }
 
 
@@ -606,6 +620,7 @@ def _offline_pipeline(
         slt, ct, ex = hierarchy_fixed(
             eu, ev, ew, valid, n_valid, n_b, mcs,
             method=method, allow_single_cluster=allow_single,
+            impl=hierarchy_impl(rep.shape[0], use_ref, mesh),
         )
     out.update({
         "eu": eu, "ev": ev, "ew": ew, "valid": valid,
@@ -979,10 +994,10 @@ def incremental_update(
 
 
 # trace-contract: incremental_pipeline rules=f32,no-callbacks,pow2
-@functools.partial(jax.jit, static_argnames=("method", "allow_single"))
+@functools.partial(jax.jit, static_argnames=("method", "allow_single", "use_ref"))
 def _incremental_pipeline(
     X, mst_u, mst_v, mst_raw, mst_valid, cd, alive, n_alive, mcs,
-    method: str = "eom", allow_single: bool = False,
+    method: str = "eom", allow_single: bool = False, use_ref: bool = False,
 ):
     """Maintained MST buffers → flat labels, skipping d_m → Borůvka.
 
@@ -1007,6 +1022,7 @@ def _incremental_pipeline(
     slt, ct, ex = hierarchy_fixed(
         eu, ev, ew, mst_valid, n_alive, weights, mcs,
         method=method, allow_single_cluster=allow_single,
+        impl=hierarchy_impl(Np, use_ref),
     )
     return {
         "rep": X[perm],
@@ -1026,7 +1042,7 @@ def _incremental_pipeline(
 
 def incremental_recluster(
     state, min_cluster_size: float, method: str = "eom",
-    allow_single_cluster: bool = False,
+    allow_single_cluster: bool = False, use_ref: bool | None = None,
 ):
     """Labels straight from an incrementally maintained MST (DynState).
 
@@ -1040,15 +1056,17 @@ def incremental_recluster(
     """
     n = int(state.n_alive)
     mcs = float(min_cluster_size)
+    use = _resolve_ref(use_ref)
     out = _incremental_pipeline(
         state.X, state.mst_u, state.mst_v, state.mst_raw, state.mst_valid,
         state.cd, state.alive, jnp.asarray(n, jnp.int32),
         jnp.asarray(mcs, jnp.float32),
-        method, bool(allow_single_cluster),
+        method, bool(allow_single_cluster), use,
     )
     out = jax.device_get(out)  # ONE host sync: labels, arrays, serve reps
     rep = out.pop("rep")[:n]
     result = _unwrap_result(out, n, mcs, np.ones(n, dtype=np.float64))
+    result.stages = {"hierarchy": hierarchy_impl(int(state.alive.shape[0]), use)}
     alive_slots = np.nonzero(np.asarray(state.alive))[0]
     return result, alive_slots, rep
 
@@ -1166,14 +1184,15 @@ class ClusterBackend:
 
     def make_dynamic(self, min_pts: int, dim: int, capacity: int = 256, **kw):
         """Incremental-maintenance handle (core.dynamic_jax).  The
-        update scans are backend-independent jnp (like hierarchy_jax);
-        the backend still owns the serve-plane assign kernels."""
+        update scans are backend-independent jnp; the backend still owns
+        the hierarchy sweeps (`incremental_recluster`) and the
+        serve-plane assign kernels."""
         from repro.core.dynamic_jax import DynamicJaxHDBSCAN
 
         return DynamicJaxHDBSCAN(min_pts, dim, capacity=capacity, **kw)
 
     def incremental_recluster(self, state, min_cluster_size: float, **kw):
-        return incremental_recluster(state, min_cluster_size, **kw)
+        return incremental_recluster(state, min_cluster_size, use_ref=self.use_ref, **kw)
 
 
 def get_backend(name: str = "auto", spatial_index: bool = False) -> ClusterBackend:

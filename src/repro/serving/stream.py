@@ -412,6 +412,9 @@ class StreamingClusterEngine:
             # over the passes (`OfflineClusterResult.mst_rounds` etc.)
             "mst_rounds_total": 0,
             "mst_strip_calls_total": 0,
+            # passes whose hierarchy sweeps ran on the SMEM kernels, not
+            # the jnp scans (`OfflineClusterResult.stages["hierarchy"]`)
+            "hierarchy_kernel_passes_total": 0,
             "recluster_skipped_busy": 0,
             "recluster_failures": 0,
             "offline_seconds_total": 0.0,
@@ -821,6 +824,8 @@ class StreamingClusterEngine:
         self.stats["recluster_count"] += 1
         self.stats["mst_rounds_total"] += res.mst_rounds
         self.stats["mst_strip_calls_total"] += res.mst_strip_calls
+        if res.stages.get("hierarchy", "jnp") != "jnp":
+            self.stats["hierarchy_kernel_passes_total"] += 1
         self.stats["offline_seconds_total"] += wall
         self.stats["offline_impl"] = dict(res.stages)
         return snap

@@ -134,6 +134,22 @@ def test_device_table_pipeline_32k(compiled_kernels):
     assert "tpu_custom_call" in hlo
 
 
+@pytest.mark.parametrize("Lp", [8192, 32768])
+def test_hierarchy_sweeps(compiled_kernels, Lp):
+    """The hierarchy's three SMEM sweeps at both deployment buckets: the
+    resident state and stream buffers fit the 1 MiB of SMEM."""
+    from repro.kernels import hierarchy as hk
+
+    S = compiled_kernels
+    i32 = jnp.int32
+    for fn, args in (
+        (hk.single_linkage_sweep, (S((Lp,), i32), S((Lp,), i32), S((Lp,)), S((Lp,)))),
+        (hk.condense_sweep, (S((Lp,), i32), S((Lp,), i32), S((Lp,)), S((Lp,), i32))),
+        (hk.eom_sweep, (S((2 * Lp,)), S((2 * Lp,), i32), S((2 * Lp,), i32), S((), i32))),
+    ):
+        assert "tpu_custom_call" in _compile(fn, *args)
+
+
 def test_offline_pipeline_8k(compiled_kernels):
     """The fused dense ε-pass at Lp = 8192 holds the Mosaic kernels."""
     S = compiled_kernels
@@ -142,7 +158,8 @@ def test_offline_pipeline_8k(compiled_kernels):
         functools.partial(ops._offline_pipeline, min_pts=15, use_ref=False),
         S((Lp, D)), S((Lp,)), S((Lp,)), S((), jnp.int32), S(()),
     )
-    assert hlo.count("tpu_custom_call") >= 2  # Eq. 6 strips + d_m tiles
+    # Eq. 6 strips + d_m tiles + the three hierarchy sweeps
+    assert hlo.count("tpu_custom_call") >= 5
 
 
 def test_fused_query_1024(compiled_kernels):
