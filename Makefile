@@ -7,11 +7,10 @@ export PYTHONPATH := src:$(PYTHONPATH)
 
 # ruff-format adoption list: files here are kept black-clean; the
 # pre-existing tree is linted (ruff check) but not reflowed wholesale.
-FORMAT_PATHS ?= scripts/check_bench_regression.py tools/lint tools/audit \
+FORMAT_PATHS ?= tools/lint tools/audit \
   src/repro/serving/tenants.py src/repro/core/device_table.py
 
-.PHONY: test test-multidevice bench-smoke bench-gate docs-links lint \
-  lint-deep audit check
+.PHONY: test test-multidevice docs-links lint lint-deep audit check
 
 test:
 	$(PYTHON) -m pytest $(PYTEST_FLAGS)
@@ -26,15 +25,6 @@ test-multidevice:
 	  tests/test_sharding.py tests/test_streaming.py tests/test_bubble_flat.py \
 	  tests/test_grid_pruning.py
 
-bench-smoke:
-	$(PYTHON) -m benchmarks.run --only fig8,fig3_dynamic,fig5_query,fig7_pruned,fig7_mesh,fig9
-
-# CI perf gate: fresh smoke run (bench_out/ by default), compared against
-# the checked-in bench_results/ baselines (1.5x default; REPRO_BENCH_TOL=…).
-# Refresh baselines deliberately: REPRO_BENCH_DIR=bench_results make bench-smoke
-bench-gate: bench-smoke
-	$(PYTHON) scripts/check_bench_regression.py --fresh bench_out
-
 docs-links:
 	$(PYTHON) scripts/check_docs_links.py
 
@@ -46,7 +36,7 @@ lint:
 # dtype discipline (RPL2xx), serve-plane lock discipline (RPL3xx), kernel
 # hygiene (RPL4xx).  Exit 1 = new findings, exit 2 = baseline drift.
 lint-deep:
-	$(PYTHON) -m tools.lint src tests benchmarks scripts
+	$(PYTHON) -m tools.lint src tests scripts
 
 # jaxpr-audit (tools/audit): abstract-trace contract analysis over every
 # registered jit entry point (DESIGN.md §14) — f64/callback/pow-2/dense

@@ -4,7 +4,7 @@ not resolve to an actual section heading in DESIGN.md (the `docs-links`
 Makefile target).
 
 A citation is any occurrence of ``DESIGN.md §N`` (or ``DESIGN.md §N,``
-etc.) under src/, tests/, benchmarks/ or examples/.  A section heading is
+etc.) under src/, tests/ or examples/.  A section heading is
 a markdown heading line in DESIGN.md containing the same §N token.
 """
 
@@ -16,7 +16,7 @@ import sys
 
 ROOT = pathlib.Path(__file__).resolve().parent.parent
 CITE = re.compile(r"DESIGN\.md[^§\n]{0,20}§(\d+)")
-SCAN_DIRS = ("src", "tests", "benchmarks", "examples")
+SCAN_DIRS = ("src", "tests", "examples")
 
 
 def main() -> int:

@@ -22,9 +22,8 @@ algebra + a small explicit-edge MST pass*:
 
 RkNN queries (Appendix A) become masked predicates over one distance row:
 ``RkNN(p) = { q : d(p,q) < cd(q) }``.  Correctness (not complexity) is
-identical to the paper's; the feasibility *benchmark* (fig3) reproduces the
-paper's finding that per-update cost approaches static recomputation as
-the update fraction grows.
+identical to the paper's, whose feasibility study finds that per-update
+cost approaches static recomputation as the update fraction grows.
 """
 
 from __future__ import annotations

@@ -29,11 +29,10 @@ EXACT, not approximate:
 
 The grid itself is backend-independent jnp (the same status as
 `core.hierarchy_jax` / `core.dynamic_jax`): both `ClusterBackend`
-flavors route through it when ``spatial_index=True``, and its outputs
-are pinned bitwise against the DENSE jnp reference path by
-tests/test_grid_pruning.py.  (The two dense backends themselves differ
-by ulps in a few epilogue ops on CPU interpret mode, so "bit-exact" is
-anchored at the jnp reference — the repo's allclose ground truth.)
+flavors route through it when ``spatial_index=True``.  Bits also
+follow XLA's fusion choices, which differ between programs and
+machines, so tests/test_grid_pruning.py holds its outputs and the dense
+paths' to the same float64 oracle, with ties admitted explicitly.
 
 Scope caveat: rows marked invalid (size-bucket padding, dead slots
 parked at ``ops._PAD_COORD``) are excluded from the candidate set

@@ -76,8 +76,7 @@ def _nearest(x, reps):
     a caller wants the distance itself).
 
     Two deliberate deviations from a naive `argmin(pairwise_sqdist(…))`,
-    both for the serve-plane latency gate (benchmarks/fig5_latency.py
-    query section):
+    both for serve-plane query latency:
       * ‖x‖² is elided from the minimized matrix — it is constant per
         row, so the argmin is invariant and one full (n, L) broadcast
         pass disappears;

@@ -1,7 +1,7 @@
 """Persistent XLA compilation cache for the entry points.
 
-`chip_smoke.py` and `benchmarks/run.py` call `enable_compile_cache()`
-before they compile anything; library imports never do.  When
+`chip_smoke.py` calls `enable_compile_cache()` before it compiles
+anything; library imports never do.  When
 ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and nothing
 here overrides it.  Otherwise the cache lives at the fixed
 ``<checkout>/.jax_cache`` — a fixed path, because the path is part of

@@ -1,7 +1,7 @@
 """Production meshes (single- and multi-pod).
 
 A FUNCTION, not a module constant, so importing this module never touches
-jax device state (smoke tests see 1 device; only dryrun.py forces 512).
+jax device state (smoke tests see 1 device).
 
 Mesh anatomy (TPU v5e pods of 256 chips):
   single pod  : (16, 16)       axes ("data", "model")

@@ -8,15 +8,13 @@ reverse.
 """
 # lock-order: QueryBatcher._dispatch -> TenantRouter._lock -> StreamingClusterEngine._snapshot_lock -> SnapshotDeviceCache._lock
 
-from .engine import HostBatcher, Request, ServeEngine
+from .batcher import HostBatcher
 from .query import QueryBatcher, QueryEngine, QueryResult, SnapshotDeviceCache
 from .stream import ClusterSnapshot, StalenessPolicy, StreamingClusterEngine, Ticket
 from .tenants import TenantRouter
 
 __all__ = [
     "HostBatcher",
-    "Request",
-    "ServeEngine",
     "ClusterSnapshot",
     "QueryBatcher",
     "QueryEngine",

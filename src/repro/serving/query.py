@@ -69,7 +69,7 @@ from repro.kernels import ops
 from repro.kernels.grid import GridIndex, build_grid, grid_assign
 from repro.spans import span
 
-from .engine import HostBatcher
+from .batcher import HostBatcher
 
 __all__ = [
     "QueryResult",
@@ -77,7 +77,6 @@ __all__ = [
     "SnapshotDeviceCache",
     "QueryEngine",
     "QueryBatcher",
-    "query_percall",
     "validate_query",
 ]
 
@@ -396,34 +395,6 @@ class QueryEngine:
 
     def query(self, snap, X) -> np.ndarray:
         return self.query_detailed(snap, X).labels
-
-
-def _assign_pr4(x, reps, use_ref: bool):
-    """PR 4's assignment, frozen at that revision for the A/B baseline:
-    eager pairwise + a true argmin on the jnp path.  The live
-    `kernels/ref.assign` has since moved to the xx-elided masked
-    index-min form (ref._nearest) — the historical serve path must not
-    inherit later kernel improvements, same discipline as fig8's frozen
-    "PR1 host hierarchy" leg."""
-    if not use_ref:
-        return ops.assign(x, reps, use_ref=False)  # Pallas kernel, unchanged
-    from repro.kernels import ref as _ref
-
-    sq = _ref.pairwise_sqdist(jnp.asarray(x), jnp.asarray(reps))
-    return jnp.argmin(sq, axis=1).astype(jnp.int32)
-
-
-def query_percall(backend, snap, X) -> np.ndarray:
-    """The PR 4-era per-call serve path, kept verbatim as the fig5 A/B
-    baseline and parity oracle: re-centers AND re-uploads the full
-    (L, d) rep table on every call."""
-    X = np.atleast_2d(np.asarray(X, dtype=np.float64))
-    if snap is None or snap.n_bubbles == 0:
-        return np.full(X.shape[0], -1, dtype=np.int64)
-    a = np.asarray(
-        _assign_pr4(X - snap.center, snap.bubble_rep - snap.center, backend.use_ref)
-    )
-    return snap.bubble_labels[a]
 
 
 class _QueryTicket:

@@ -81,7 +81,7 @@ from repro.kernels import ops
 from repro.launch.mesh import resolve_mesh
 from repro.spans import span
 
-from .engine import HostBatcher
+from .batcher import HostBatcher
 from .query import QueryEngine, QueryResult
 
 __all__ = [
@@ -138,8 +138,8 @@ class StalenessPolicy:
 class UpdatePolicy:
     """Crossover heuristic for the hybrid exact-dynamic fast path.
 
-    The paper's feasibility study (Fig. 3) and the fig3_dynamic bench
-    agree: incremental maintenance wins while the touched fraction is
+    The paper's feasibility study (Fig. 3) finds that incremental
+    maintenance wins while the touched fraction is
     small and loses to a from-scratch pass as it grows.  Each applied
     block is routed accordingly:
 
@@ -247,8 +247,7 @@ class StreamingClusterEngine:
         as fixed-shape jit programs, host tree consuming the emitted
         work-lists for splits/dissolves, and ε-passes reading the flat
         table with zero per-pass host→device transfer.  Default off —
-        explicit opt-in for serving-scale block workloads (the fig8
-        ingestion A/B shows where it wins, even on CPU).
+        explicit opt-in for serving-scale block workloads.
         NOTE: snapshot rows follow the flat state's
         slot order, not ascending leaf id, so callers that correlate
         snapshot rows with `leaf_cf_buffers()` must opt in knowingly.

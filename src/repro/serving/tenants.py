@@ -41,8 +41,7 @@ instances behind shared serve-plane machinery:
 
 Ingestion stays per-tenant (each engine's `poll()` drains its own
 request queue — the tree has a single writer thread by contract);
-`poll()` with no name round-robins every tenant, which is what the fig9
-service loop drives.
+`poll()` with no name round-robins every tenant.
 """
 
 from __future__ import annotations

@@ -1,5 +1,6 @@
 """Shared fixtures.  NOTE: no XLA_FLAGS here — smoke tests must see the
-real single CPU device; only launch/dryrun.py forces 512 placeholders."""
+real single CPU device; multi-device cases force their device count in
+subprocesses (tests/test_mesh_sharding.py) or via `make test-multidevice`."""
 
 import numpy as np
 import pytest

@@ -1,9 +1,6 @@
 """Framework substrates: checkpointing, data pipeline, curation, serving."""
 
-import json
 import os
-import subprocess
-import sys
 
 import jax
 import jax.numpy as jnp
@@ -16,7 +13,7 @@ from repro.data.curation import StreamCurator
 from repro.data.pipeline import TokenPipeline
 from repro.data.synthetic import dataset, gaussian_mixtures, sliding_window_workload
 from repro.models import model as M
-from repro.serving import Request, ServeEngine
+from repro.serving.engine import Request, ServeEngine
 from conftest import make_blobs
 
 
@@ -288,30 +285,3 @@ class TestServing:
         eng2.submit(req2)
         eng2.run()
         assert req2.done and req2.generated[-1] == first and len(req2.generated) <= 2
-
-
-class TestTrainDriver:
-    def test_train_resume_roundtrip(self, tmp_path):
-        """Full driver: train 6 steps, kill, resume to 10 — loss stream is
-        continuous and checkpoints land."""
-        out = str(tmp_path / "run")
-        cmd = [
-            sys.executable, "-m", "repro.launch.train", "--arch", "qwen1.5-0.5b",
-            "--smoke", "--batch", "2", "--seq", "16", "--ckpt-every", "3",
-            "--out", out, "--lr", "1e-3",
-        ]
-        env = dict(os.environ, PYTHONPATH="src")
-        r1 = subprocess.run(cmd + ["--steps", "6"], capture_output=True, text=True, env=env, timeout=600)
-        assert r1.returncode == 0, r1.stderr[-2000:]
-        assert latest_step(os.path.join(out, "ckpt")) == 6
-        r2 = subprocess.run(
-            cmd + ["--steps", "10", "--resume", "auto"],
-            capture_output=True, text=True, env=env, timeout=600,
-        )
-        assert r2.returncode == 0, r2.stderr[-2000:]
-        assert "restored step 6" in r2.stdout
-        with open(os.path.join(out, "metrics.jsonl")) as f:
-            recs = [json.loads(line) for line in f]
-        steps = [r["step"] for r in recs]
-        assert steps == list(range(6)) + list(range(6, 10))
-        assert latest_step(os.path.join(out, "ckpt")) == 10

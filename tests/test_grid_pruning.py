@@ -1,15 +1,13 @@
-"""Differential exactness suite for the grid-pruned neighbor engine
-(kernels.grid — DESIGN.md §10).
+"""Differential suite for the grid-pruned neighbor engine (kernels.grid —
+DESIGN.md §10), held to the float64 oracle.
 
-The `spatial_index=` opt-in promises BIT-EXACT results against the dense
-paths, not approximate ones; this suite pins that promise at every layer
-the grid is wired into:
+The `spatial_index=` opt-in promises EXACT results — it prunes only
+what cannot win — at every layer the grid is wired into:
 
   * Eq. 6 core distances (`ops.bubble_core_distances`),
-  * MST construction (`boruvka_grid_jax` vs `boruvka_jax` on the dense
-    mutual-reachability matrix — full edge buffers, not just weight),
-  * query/ingest assignment (`ops.assign`, index AND distance level,
-    pinning the lowest-index tie-break on duplicate-heavy data),
+  * MST construction (`boruvka_grid_jax`, and the dense `boruvka_jax`
+    it replaces),
+  * query/ingest assignment (`ops.assign`, index AND distance),
   * the fused offline pipeline (`offline_recluster_from_table`) and the
     streaming serve plane end to end,
 
@@ -18,35 +16,37 @@ both ClusterBackend flavors, plus the two grid extremes: ALL points in
 one cell (identical coordinates → zero quantization range) and one
 point per cell (spread so far every Morton cell is a singleton).
 
-Comparator discipline (the suite's one subtle rule): every dense
-comparator runs under jit.  Eager per-op dispatch picks different CPU
-gemm paths than XLA codegen inside jit — up to ~1000 ulps apart after
-catastrophic-cancellation amplification in ‖x‖²+‖y‖²−2xy — and the
-REAL dense paths the grid replaces are all jitted programs.  Comparing
-against an eager re-run would test the wrong bits.
+Every program computes in float32, so the suite holds both the pruned
+and the dense path to the same float64 answers rather than to each
+other's bits (which follow the compiler's fusion choices and differ
+between machines):
 
-Bit-exactness is anchored at the jnp reference (the repo's ground
-truth): the grid layer is backend-independent jnp, so BOTH backends'
-core distances and assignments carry the same reference bits.  The
-spatial pass's Borůvka sweeps row strips of the backend's own
-mutual-reachability kernel, so on the pallas backend its tree is held
-bitwise to the dense Borůvka over the W that kernel returns.  The dense Pallas
-interpret-mode kernels drift from that anchor by ulps in a few epilogue
-ops (documented in kernels/grid.py), so on the pallas backend the suite
-demands exact labels / indices / tie-breaks and reference-bit values,
-with cross-checks against the pallas dense leg itself restricted to the
-tie-free kinds (blobs/uniform): on dup/collinear tables exact distance
-ties abound, and the pallas ulp drift flips WHICH tied neighbor wins
-k-NN selection / argmin — an O(1) value change no tolerance can paper
-over, and not a defect in either path.
+  * distance-valued outputs (core distances, assignment distances, MST
+    weights, d_m matrices) lie within one stated f32 tolerance of the
+    float64 value (`_delta`, `_tol`);
+  * indices (nearest bubble, MST endpoints) are exact except at ties: a
+    different index passes only if its float64 distance or weight is
+    within that tolerance of the oracle's;
+  * trees are spanning trees of the valid rows whose sorted weights are
+    the float64 MST's levels and whose every edge weighs the float64 d_m
+    of its own endpoints (the benchmark's `level_gap` and `edge_gap`);
+  * labels are exactly the host HDBSCAN hierarchy (single linkage →
+    condensed tree → excess of mass) over the pass's own tree (the
+    benchmark's `hierarchy_wrong`).
+
+Eq. 6 itself is tie-sensitive: where two neighbours sit within rounding
+of each other, f32 decides their order, and the bubble C at which the
+mass crosses min_pts — whose nnDist enters the value — can change by
+O(1).  `_cd_values` therefore enumerates every value some such order
+gives, and the tree bounds use its per-row extremes.
 
 Property tests (via tests/_hypothesis_compat) cover the structural
 invariants the exactness argument rests on: the Morton sort is a
 bijection placing every valid rep in exactly one tile, tile lower
 bounds never exceed any member distance (so candidate enumeration can
 never prune a true nearest neighbor), and invalid/padded rows are
-excluded from every candidate set — results are invariant to both the
-CONTENTS of invalid rows and the amount of bucket padding.
+excluded from every candidate set — results stay on the oracle whatever
+the CONTENTS of invalid rows and the amount of bucket padding.
 """
 
 import functools
@@ -57,9 +57,16 @@ import numpy as np
 import pytest
 from _hypothesis_compat import given, settings, st
 
-from repro.core.mst import boruvka_grid_jax, boruvka_jax
+from repro.core.bubbles import DataBubbles, bubble_core_distances
+from repro.core.hdbscan import (
+    _stabilities,
+    condense_tree,
+    extract_clusters,
+    hdbscan_labels,
+    single_linkage,
+)
+from repro.core.mst import boruvka_dense, boruvka_grid_jax, boruvka_jax
 from repro.kernels import ops
-from repro.kernels import ref
 from repro.kernels.grid import (
     _block_views,
     build_grid,
@@ -103,19 +110,263 @@ def _table(kind: str, d: int, seed: int, n: int = L):
     return rep, n_b, extent
 
 
-def _bitwise(a, b, what=""):
-    a = np.asarray(a)
-    b = np.asarray(b)
-    assert a.shape == b.shape and a.dtype == b.dtype, (what, a.shape, b.shape)
-    assert a.tobytes() == b.tobytes(), (
-        what,
-        np.flatnonzero(a.reshape(-1) != b.reshape(-1))[:10],
+# ---------------------------------------------------------------------------
+# the float64 oracle
+
+TAU = 1e-6
+
+
+def _delta(*tables) -> float:
+    """The one tolerance the suite holds f32 results to, as the error
+    budget δ = TAU·S² of a squared distance, S the largest row norm of
+    the tables the program computed distances over.
+
+    Why: every f32 distance comes from the expansion ‖x‖² + ‖y‖² − 2x·y
+    at ``Precision.HIGHEST``, whose rounding error is a few ulps of S²,
+    not of d² (eps = 2⁻²³ ≈ 1.2e-7, so TAU is eight of them; the widest
+    gap seen on these tables is under one).  A distance d therefore
+    carries at most min(√δ, δ/d) (`_tol`): 1e-3·S at d = 0, under
+    1e-5·S beyond d = 0.1·S.  A wrong neighbour costs a neighbour
+    spacing, which on these tables is far above that everywhere except
+    at genuine near-ties, which the oracle admits explicitly."""
+    S = max(float(np.linalg.norm(np.asarray(t, np.float64), axis=1).max()) for t in tables)
+    return TAU * S * S
+
+
+def _tol(d, delta):
+    """What a float64 distance ``d`` may be off by in f32 (see `_delta`),
+    plus TAU relative for the rounding of the stored value."""
+    d = np.asarray(d, np.float64)
+    return np.minimum(np.sqrt(delta), delta / np.maximum(d, 1e-300)) + TAU * d
+
+
+def _dist64(x, y) -> np.ndarray:
+    """Euclidean distances in float64 from coordinate differences
+    (identical rows are exactly 0 apart)."""
+    x = np.asarray(x, np.float64)
+    y = np.asarray(y, np.float64)
+    return np.sqrt(((x[:, None, :] - y[None, :, :]) ** 2).sum(-1))
+
+
+@functools.lru_cache(maxsize=None)
+def _subset_sums(masses: tuple, cap: float) -> frozenset:
+    """Every sum < ``cap`` of a subset of ``masses``."""
+    sums = {0.0}
+    for m in masses:
+        sums |= {s + m for s in sums if s + m < cap}
+    return frozenset(sums)
+
+
+def _cd_values(rep, n_b, extent, min_pts: int, delta: float):
+    """Every value float64 Eq. 6 takes on each row when neighbours whose
+    squared distances lie within 2δ of each other (f32 cannot order
+    them) may come in either order, each with what it may be off by.
+
+    For a crossing bubble C, every bubble nearer than that band precedes
+    it, every bubble beyond it follows, and any subset of the band may
+    precede it; C's nnDist enters the value, so a tie can move it by
+    O(1).  Returns (lo, hi, vals): the per-row bounds of every admissible
+    value widened by its tolerance, and per row the (value, tolerance)
+    pairs.  The untied value, `core.bubbles.bubble_core_distances`, is
+    always among them (asserted)."""
+    rep = np.asarray(rep, np.float64)
+    n = np.asarray(n_b, np.float64)
+    ext = np.asarray(extent, np.float64)
+    Lr, dim = rep.shape
+    mp = float(min_pts)
+    D = _dist64(rep, rep)
+    vals = []
+    for b in range(Lr):
+        d, d2 = D[b], D[b] ** 2
+        order = np.argsort(d, kind="stable")
+        reach = d2[order[min(int(np.searchsorted(np.cumsum(n[order]), mp)), Lr - 1)]]
+        row = set()
+        for c in np.flatnonzero(d2 <= reach + 2 * delta):
+            base = float(n[d2 < d2[c] - 2 * delta].sum())
+            if base >= mp:
+                continue
+            may = np.abs(d2 - d2[c]) <= 2 * delta
+            may[c] = False
+            nC = max(float(n[c]), 1.0)
+            for s in _subset_sums(tuple(np.sort(n[may])), mp - base):
+                if base + s + n[c] >= mp:
+                    k = min(max(mp - base - s, 1.0), nC)
+                    v = d[c] + (k / nC) ** (1.0 / dim) * ext[c]
+                    row.add((float(v), float(_tol(d[c], delta) + TAU * v)))
+        vals.append(np.array(sorted(row)))
+    want = bubble_core_distances(DataBubbles(rep, n, ext, dim), min_pts)
+    for b in range(Lr):
+        v, t = vals[b].T
+        assert (np.abs(v - want[b]) <= t).any(), ("oracle", b, want[b], vals[b])
+    lo = np.array([(v[:, 0] - v[:, 1]).min() for v in vals])
+    hi = np.array([(v[:, 0] + v[:, 1]).max() for v in vals])
+    return lo, hi, vals
+
+
+def _assert_cd(got, rep, n_b, extent, min_pts, delta, what):
+    """Core distances on the float64 Eq. 6 (tie-aware); returns the
+    oracle's per-row (lo, hi)."""
+    lo, hi, vals = _cd_values(rep, n_b, extent, min_pts, delta)
+    got = np.asarray(got, np.float64)
+    assert got.shape == lo.shape, (what, got.shape, lo.shape)
+    bad = [b for b in range(lo.shape[0])
+           if not (np.abs(vals[b][:, 0] - got[b]) <= vals[b][:, 1]).any()]
+    assert not bad, (what, "cd", bad[:10], got[bad[:5]], [vals[b] for b in bad[:5]])
+    return lo, hi
+
+
+def _assert_nearest(idx, dist, x, reps, delta, what, valid=None):
+    """Nearest-bubble indices (and distances, unless ``dist`` is None)
+    on the float64 nearest search: a row's index may differ from the
+    oracle's only where the two squared distances are within the 2δ f32
+    cannot resolve."""
+    D = _dist64(x, reps)
+    if valid is not None:
+        D[:, ~np.asarray(valid)] = np.inf
+    idx = np.asarray(idx, np.int64)
+    assert idx.shape == (D.shape[0],), (what, idx.shape)
+    assert ((idx >= 0) & (idx < D.shape[1])).all(), (what, "index range")
+    got = D[np.arange(idx.shape[0]), idx]
+    best = D.min(axis=1)
+    bad = np.flatnonzero(got**2 > best**2 + 2 * delta)
+    assert not bad.size, (what, "idx", bad[:10], idx[bad[:5]], D[bad[:5]].argmin(1))
+    if dist is not None:
+        dist = np.asarray(dist, np.float64)
+        bad = np.flatnonzero(np.abs(dist - got) > _tol(got, delta))
+        assert not bad.size, (what, "dist", bad[:10], dist[bad[:5]], got[bad[:5]])
+
+
+def _levels(W) -> np.ndarray:
+    """Sorted weights of the float64 minimum spanning tree of ``W``."""
+    W = np.array(W, np.float64)
+    np.fill_diagonal(W, np.inf)
+    return np.sort(boruvka_dense(W)[2])
+
+
+def _assert_mst(u, v, w, W_lo, W_hi, what):
+    """(u, v, w) is a minimum spanning tree of some matrix between W_lo
+    and W_hi: a spanning tree of their rows, each edge weighing its own
+    endpoints' entry (the benchmark's ``edge_gap``), and its sorted
+    weights between the two matrices' MST levels (``level_gap``; the
+    k-th level of an MST only grows with the matrix)."""
+    Lr = W_lo.shape[0]
+    u = np.asarray(u, np.int64)
+    v = np.asarray(v, np.int64)
+    w = np.asarray(w, np.float64)
+    assert u.shape == v.shape == w.shape == (Lr - 1,), (what, u.shape, Lr)
+    assert ((u >= 0) & (u < Lr) & (v >= 0) & (v < Lr)).all(), (what, "endpoint")
+    root = list(range(Lr))
+
+    def find(a):
+        while root[a] != a:
+            root[a] = root[root[a]]
+            a = root[a]
+        return a
+
+    for a, b in zip(u.tolist(), v.tolist()):
+        ra, rb = find(a), find(b)
+        assert ra != rb, (what, "cycle", a, b)
+        root[ra] = rb
+    e_lo, e_hi = W_lo[u, v], W_hi[u, v]
+    bad = np.flatnonzero((w < e_lo) | (w > e_hi))
+    assert not bad.size, (what, "edge", bad[:10], w[bad[:5]], e_lo[bad[:5]], e_hi[bad[:5]])
+    ws, l_lo, l_hi = np.sort(w), _levels(W_lo), _levels(W_hi)
+    bad = np.flatnonzero((ws < l_lo) | (ws > l_hi))
+    assert not bad.size, (what, "level", bad[:10], ws[bad[:5]], l_lo[bad[:5]], l_hi[bad[:5]])
+
+
+def _dm_bounds(rep, lo, hi, delta):
+    """Bounds on the f32 d_m = max(cd_i, cd_j, d_ij) matrix: core
+    distances anywhere in the oracle's [lo, hi], distances within their
+    tolerance."""
+    D = _dist64(rep, rep)
+    t = _tol(D, delta)
+    W_lo = np.maximum(D - t, np.maximum(lo[:, None], lo[None, :]))
+    W_hi = np.maximum(D + t, np.maximum(hi[:, None], hi[None, :]))
+    return W_lo, W_hi
+
+
+def _edges(eu, ev, ew, valid):
+    keep = np.asarray(valid)
+    return np.asarray(eu)[keep], np.asarray(ev)[keep], np.asarray(ew)[keep]
+
+
+def _hierarchy(u, v, w, n_b, mcs):
+    """Host HDBSCAN over a given tree: (labels, stability per label)."""
+    n_b = np.asarray(n_b, np.float64)
+    slt = single_linkage(
+        np.asarray(u), np.asarray(v), np.asarray(w, np.float64), n_b.shape[0], weights=n_b
     )
+    ct = condense_tree(slt, min_cluster_size=float(mcs))
+    selected = extract_clusters(ct)
+    stab = _stabilities(ct)
+    return hdbscan_labels(ct, selected), np.array([stab[c] for c in selected])
+
+
+def _assert_same_partition(a, b, what):
+    """Equal up to the names of the clusters, noise as noise; returns the
+    a-label → b-label map."""
+    a = np.asarray(a, np.int64)
+    b = np.asarray(b, np.int64)
+    assert a.shape == b.shape, (what, a.shape, b.shape)
+    bad = np.flatnonzero((a == -1) != (b == -1))
+    assert not bad.size, (what, "noise", bad[:10])
+    pairs = set(zip(a[a >= 0].tolist(), b[b >= 0].tolist()))
+    names = dict(pairs)
+    assert len(pairs) == len(names) == len(set(names.values())), (what, "partition", pairs)
+    return names
+
+
+def _assert_labels(res, n_b, mcs, what):
+    """A pass's labels and stabilities are exactly the host hierarchy of
+    its own tree (stabilities to f32 summation error)."""
+    u, v, w = res.mst
+    want, stab = _hierarchy(u, v, w, n_b, mcs)
+    names = _assert_same_partition(res.labels, want, what)
+    got = np.asarray(res.stabilities, np.float64)
+    assert got.shape == stab.shape, (what, got.shape, stab.shape)
+    for k, j in names.items():
+        np.testing.assert_allclose(got[k], stab[j], rtol=1e-4, err_msg=what)
+
+
+def _centered(rep, n_b):
+    """The frame `offline_recluster_from_table` computes in."""
+    rep = np.asarray(rep, np.float64)
+    n_b = np.asarray(n_b, np.float64)
+    return rep - (n_b @ rep) / n_b.sum()
+
+
+def _own_bounds(W, rep, delta):
+    """A tree built from the d_m matrix ``W`` its pass computed holds to
+    that W up to the tolerance of the distances alone: the core
+    distances it was built from are the ones in W."""
+    W = np.asarray(W, np.float64)
+    t = _tol(_dist64(rep, rep), delta)
+    return W - t, W + t
+
+
+def _assert_pass(res, W, rep, n_b, extent, min_pts, what):
+    """One offline pass on the oracle: the d_m matrix it returned (None
+    when it built none) on the float64 one (tie-aware), its tree a
+    minimum spanning tree of that matrix — or, without one, of some
+    matrix the oracle admits — and its labels the hierarchy of its tree."""
+    delta = _delta(_centered(rep, n_b))
+    lo, hi, _ = _cd_values(rep, n_b, extent, min_pts, delta)
+    W_lo, W_hi = _dm_bounds(rep, lo, hi, delta)
+    if W is not None:
+        W = np.asarray(W, np.float64)
+        off = ~np.eye(W.shape[0], dtype=bool)
+        bad = off & ((W < W_lo) | (W > W_hi))
+        assert not bad.any(), (what, "W", np.argwhere(bad)[:5])
+        assert (np.diag(W) == 0.0).all(), (what, "W diagonal")
+        W_lo, W_hi = _own_bounds(W, rep, delta)
+    _assert_mst(*res.mst, W_lo, W_hi, what)
+    _assert_labels(res, n_b, min_pts, what)
 
 
 # ---------------------------------------------------------------------------
-# jitted comparator wrappers (see module docstring: dense legs MUST be
-# the jitted programs the grid actually replaces)
+# jitted wrappers: the paths under test are jitted programs, so they
+# run as such here
 
 _assign_dense = jax.jit(
     functools.partial(ops.assign, with_dist=True), static_argnames=("use_ref",)
@@ -129,14 +380,14 @@ _assign_grid = jax.jit(
 def _mst_grid(repp, valid, nbp, extp, min_pts, dim):
     g = build_grid(repp, valid)
     cd = grid_core_distances(g, nbp, extp, min_pts, dim)
-    return boruvka_grid_jax(g, cd)
+    return cd, boruvka_grid_jax(g, cd)
 
 
 @functools.partial(jax.jit, static_argnames=("min_pts",))
 def _mst_dense(repp, is_pad, nbp, extp, min_pts):
     W = ops.bubble_mutual_reachability(repp, nbp, extp, min_pts, use_ref=True)
     W = jnp.where(is_pad[:, None] | is_pad[None, :], jnp.inf, W)
-    return boruvka_jax(W)
+    return W, boruvka_jax(W)
 
 
 def _pad_table(rep, n_b, extent):
@@ -160,38 +411,26 @@ class TestCoreDistanceParity:
     @pytest.mark.parametrize("kind", KINDS)
     @pytest.mark.parametrize("use_ref", BACKENDS, ids=["jnp", "pallas"])
     def test_bitwise(self, kind, d, use_ref):
+        """Dense and pruned core distances both on the float64 Eq. 6."""
         rep, n_b, extent = _table(kind, d, seed=d * 17 + len(kind))
+        delta = _delta(rep)
         dense = ops.bubble_core_distances(rep, n_b, extent, MIN_PTS, use_ref=use_ref)
         pruned = ops.bubble_core_distances(
             rep, n_b, extent, MIN_PTS, use_ref=use_ref, spatial_index=True
         )
-        if use_ref:
-            _bitwise(dense, pruned, f"cd {kind} d={d}")
-        else:
-            # the pallas strip kernel drifts by ulps from the reference
-            # anchor; the spatial path must carry reference bits EXACTLY
-            # on this backend too (it is the same jnp program)
-            anchor = ops.bubble_core_distances(rep, n_b, extent, MIN_PTS, use_ref=True)
-            _bitwise(anchor, pruned, f"cd-vs-ref {kind} d={d}")
-            if kind in ("blobs", "uniform"):
-                # cross-check vs the drifting pallas dense leg only where
-                # pairwise distances are tie-free: on dup/collinear tables
-                # exact ties abound and ulp-level drift flips WHICH
-                # neighbor is k-th, so the dense pallas value can differ
-                # from the anchor by O(1), not O(eps) — the reference
-                # bitwise check above is the contract there
-                np.testing.assert_allclose(
-                    np.asarray(dense), np.asarray(pruned), rtol=1e-3, atol=1e-5
-                )
+        _assert_cd(dense, rep, n_b, extent, MIN_PTS, delta, f"cd dense {kind} d={d}")
+        _assert_cd(pruned, rep, n_b, extent, MIN_PTS, delta, f"cd pruned {kind} d={d}")
 
     def test_min_pts_sweep(self):
         rep, n_b, extent = _table("blobs", 8, seed=3)
+        delta = _delta(rep)
         for mp in (1, 2, 7, 30):
             dense = ops.bubble_core_distances(rep, n_b, extent, mp, use_ref=True)
             pruned = ops.bubble_core_distances(
                 rep, n_b, extent, mp, spatial_index=True
             )
-            _bitwise(dense, pruned, f"cd min_pts={mp}")
+            _assert_cd(dense, rep, n_b, extent, mp, delta, f"cd dense min_pts={mp}")
+            _assert_cd(pruned, rep, n_b, extent, mp, delta, f"cd pruned min_pts={mp}")
 
 
 class TestAssignParity:
@@ -207,58 +446,65 @@ class TestAssignParity:
                 rng.normal(0.0, 6.0, (29, d)).astype(np.float32),  # off-manifold
             ]
         )
+        delta = _delta(x, rep)
         di, dd = _assign_dense(x, rep, use_ref=use_ref)
         gi, gd = _assign_grid(x, rep)
-        # index-level parity pins the lowest-index tie-break; index and
-        # distance bits are anchored at the jnp reference on BOTH backends
-        if use_ref:
-            _bitwise(di, gi, f"assign idx {kind} d={d}")
-            _bitwise(dd, gd, f"assign dist {kind} d={d}")
-        else:
-            ri, rd_ = _assign_dense(x, rep, use_ref=True)
-            _bitwise(ri, gi, f"assign idx-vs-ref {kind} d={d}")
-            _bitwise(rd_, gd, f"assign dist-vs-ref {kind} d={d}")
-            if kind in ("blobs", "uniform"):
-                # vs the drifting pallas dense leg only on tie-free data:
-                # dup/collinear queries sit equidistant to several reps,
-                # where ulp drift legitimately flips the argmin winner —
-                # the reference anchors above are the contract there
-                _bitwise(di, gi, f"assign idx {kind} d={d} pallas")
-                np.testing.assert_allclose(
-                    np.asarray(dd), np.asarray(gd), rtol=1e-4, atol=1e-5
-                )
+        _assert_nearest(di, dd, x, rep, delta, f"assign dense {kind} d={d}")
+        _assert_nearest(gi, gd, x, rep, delta, f"assign pruned {kind} d={d}")
 
     def test_duplicate_tie_break_pinned(self):
-        # every query equidistant to many identical reps: the winner must
-        # be the LOWEST original row index, exactly like the dense argmin
+        # every query equidistant to many identical reps, on coordinates
+        # whose arithmetic is exact in f32: the winner must be the LOWEST
+        # row index among the tied reps, on both paths
         rep = np.tile(np.array([[1.5, -2.0]], np.float32), (64, 1))
         rep[::7] += 4.0  # two duplicate clusters
         x = np.array([[1.5, -2.0], [5.5, 2.0], [3.0, 0.0]], np.float32)
-        di, dd = _assign_dense(x, rep, use_ref=True)
-        gi, gd = _assign_grid(x, rep)
-        _bitwise(di, gi, "dup tie idx")
-        _bitwise(dd, gd, "dup tie dist")
+        D = _dist64(x, rep)
+        want = np.argmin(D, axis=1)  # first occurrence of the exact minimum
+        for name, (i, dist) in (
+            ("dense", _assign_dense(x, rep, use_ref=True)),
+            ("pruned", _assign_grid(x, rep)),
+        ):
+            np.testing.assert_array_equal(np.asarray(i), want, err_msg=f"dup tie {name}")
+            np.testing.assert_array_equal(
+                np.asarray(dist, np.float64), D.min(axis=1), err_msg=f"dup tie {name}"
+            )
 
 
 class TestMstParity:
     @pytest.mark.parametrize("d", [2, 8, 16])
     @pytest.mark.parametrize("kind", KINDS)
     def test_full_edge_buffers(self, kind, d):
+        """The grid Borůvka and the dense one it replaces: both spanning
+        trees of the valid rows on the float64 MST, and the grid pass's
+        own core distances on the float64 Eq. 6."""
         rep, n_b, extent = _table(kind, d, seed=d * 13 + len(kind))
         repp, nbp, extp, valid = _pad_table(rep, n_b, extent)
-        ge = _mst_grid(repp, jnp.asarray(valid), nbp, extp, MIN_PTS, d)
-        de = _mst_dense(repp, jnp.asarray(~valid), nbp, extp, MIN_PTS)
-        for name, g, dn in zip(("eu", "ev", "ew", "valid"), ge, de):
-            _bitwise(dn, g, f"mst {name} {kind} d={d}")
-        gw = np.asarray(ge[2])[np.asarray(ge[3])]
-        dw = np.asarray(de[2])[np.asarray(de[3])]
-        _bitwise(dw.sum(), gw.sum(), f"mst total weight {kind} d={d}")
+        delta = _delta(rep)
+        cd, ge = _mst_grid(repp, jnp.asarray(valid), nbp, extp, MIN_PTS, d)
+        W, de = _mst_dense(repp, jnp.asarray(~valid), nbp, extp, MIN_PTS)
+        cd = np.asarray(cd, np.float64)[valid]
+        W = np.asarray(W)[valid][:, valid]
+        lo, hi = _assert_cd(cd, rep, n_b, extent, MIN_PTS, delta, f"mst cd {kind} d={d}")
+        W_lo, W_hi = _dm_bounds(rep, lo, hi, delta)
+        assert ((W >= W_lo) & (W <= W_hi) | np.eye(L, dtype=bool)).all(), f"W {kind} d={d}"
+        # each tree against the d_m of the core distances it was built from
+        _assert_mst(*_edges(*de), *_own_bounds(W, rep, delta), f"mst dense {kind} d={d}")
+        D = _dist64(rep, rep)
+        t = _tol(D, delta)
+        cdm = np.maximum(cd[:, None], cd[None, :])
+        _assert_mst(
+            *_edges(*ge), np.maximum(D - t, cdm), np.maximum(D + t, cdm),
+            f"mst pruned {kind} d={d}",
+        )
 
 
 class TestPipelineParity:
     @pytest.mark.parametrize("kind", KINDS)
     @pytest.mark.parametrize("use_ref", BACKENDS, ids=["jnp", "pallas"])
     def test_labels_mst_w(self, kind, use_ref):
+        """Dense and spatial passes on the oracle: the d_m matrix each
+        returns, its tree, labels and stabilities."""
         rep, n_b, extent = _table(kind, 8, seed=len(kind))
         Wd, rd = ops.offline_recluster_from_table(
             rep, n_b, extent, MIN_PTS, use_ref=use_ref, return_w=True
@@ -267,40 +513,20 @@ class TestPipelineParity:
             rep, n_b, extent, MIN_PTS, use_ref=use_ref, return_w=True,
             spatial_index=True,
         )
-        _bitwise(rd.labels, rs.labels, f"labels {kind} ref={use_ref}")
-        if use_ref:
-            for a, b, nm in zip(rd.mst, rs.mst, "uvw"):
-                _bitwise(a, b, f"mst.{nm} {kind}")
-            _bitwise(np.asarray(Wd), np.asarray(Ws), f"W {kind}")
-            _bitwise(rd.stabilities, rs.stabilities, f"stabilities {kind}")
-        else:
-            np.testing.assert_allclose(rd.mst[2], rs.mst[2], rtol=1e-4, atol=1e-6)
-            np.testing.assert_allclose(
-                np.asarray(Wd), np.asarray(Ws), rtol=1e-4, atol=1e-6
-            )
-            # the spatial pass's Borůvka sweeps strips of this backend's
-            # kernel: its tree is the dense Borůvka over the W it returns
-            # (the same kernel over the grid's core distances), bitwise
-            eu, ev, ew, valid = jax.jit(boruvka_jax)(jnp.asarray(Ws))
-            keep = np.asarray(valid)
-            for a, b, nm in zip(
-                (np.asarray(eu)[keep].astype(np.int64),
-                 np.asarray(ev)[keep].astype(np.int64),
-                 np.asarray(ew)[keep].astype(np.float64)),
-                rs.mst, "uvw",
-            ):
-                _bitwise(a, b, f"mst.{nm} vs own W {kind}")
+        _assert_pass(rd, Wd, rep, n_b, extent, MIN_PTS, f"dense {kind} ref={use_ref}")
+        _assert_pass(rs, Ws, rep, n_b, extent, MIN_PTS, f"spatial {kind} ref={use_ref}")
 
     @pytest.mark.parametrize("d", [2, 16])
     def test_labels_other_dims(self, d):
         rep, n_b, extent = _table("blobs", d, seed=d)
-        rd = ops.offline_recluster_from_table(rep, n_b, extent, MIN_PTS, use_ref=True)
+        Wd, rd = ops.offline_recluster_from_table(
+            rep, n_b, extent, MIN_PTS, use_ref=True, return_w=True
+        )
         rs = ops.offline_recluster_from_table(
             rep, n_b, extent, MIN_PTS, use_ref=True, spatial_index=True
         )
-        _bitwise(rd.labels, rs.labels, f"labels d={d}")
-        for a, b, nm in zip(rd.mst, rs.mst, "uvw"):
-            _bitwise(a, b, f"mst.{nm} d={d}")
+        _assert_pass(rd, Wd, rep, n_b, extent, MIN_PTS, f"dense d={d}")
+        _assert_pass(rs, None, rep, n_b, extent, MIN_PTS, f"spatial d={d}")
 
 
 class TestGridExtremes:
@@ -313,19 +539,7 @@ class TestGridExtremes:
         rng = np.random.default_rng(d)
         n_b = rng.integers(1, 5, L).astype(np.float32)
         extent = np.abs(rng.normal(0.1, 0.02, L)).astype(np.float32)
-        dense = ops.bubble_core_distances(rep, n_b, extent, MIN_PTS, use_ref=True)
-        pruned = ops.bubble_core_distances(rep, n_b, extent, MIN_PTS, spatial_index=True)
-        _bitwise(dense, pruned, f"one-cell cd d={d}")
-        x = np.concatenate([rep[:5], rep[:5] + 0.5])
-        di, dd = _assign_dense(x, rep, use_ref=True)
-        gi, gd = _assign_grid(x, rep)
-        _bitwise(di, gi, f"one-cell assign idx d={d}")
-        _bitwise(dd, gd, f"one-cell assign dist d={d}")
-        rd = ops.offline_recluster_from_table(rep, n_b, extent, MIN_PTS, use_ref=True)
-        rs = ops.offline_recluster_from_table(
-            rep, n_b, extent, MIN_PTS, use_ref=True, spatial_index=True
-        )
-        _bitwise(rd.labels, rs.labels, f"one-cell labels d={d}")
+        self._check(rep, n_b, extent, np.concatenate([rep[:5], rep[:5] + 0.5]), d)
 
     @pytest.mark.parametrize("d", DIMS)
     def test_one_point_per_cell(self, d):
@@ -337,19 +551,29 @@ class TestGridExtremes:
         )
         n_b = rng.integers(1, 5, L).astype(np.float32)
         extent = np.abs(rng.normal(0.1, 0.02, L)).astype(np.float32)
+        x = (rep[:32] + rng.normal(0, 20, (32, d))).astype(np.float32)
+        self._check(rep, n_b, extent, x, d)
+
+    @staticmethod
+    def _check(rep, n_b, extent, x, d):
+        delta = _delta(rep)
         dense = ops.bubble_core_distances(rep, n_b, extent, MIN_PTS, use_ref=True)
         pruned = ops.bubble_core_distances(rep, n_b, extent, MIN_PTS, spatial_index=True)
-        _bitwise(dense, pruned, f"singleton cd d={d}")
-        x = (rep[:32] + rng.normal(0, 20, (32, d))).astype(np.float32)
+        _assert_cd(dense, rep, n_b, extent, MIN_PTS, delta, f"extreme cd dense d={d}")
+        _assert_cd(pruned, rep, n_b, extent, MIN_PTS, delta, f"extreme cd pruned d={d}")
+        delta_q = _delta(x, rep)
         di, dd = _assign_dense(x, rep, use_ref=True)
         gi, gd = _assign_grid(x, rep)
-        _bitwise(di, gi, f"singleton assign idx d={d}")
-        _bitwise(dd, gd, f"singleton assign dist d={d}")
-        rd = ops.offline_recluster_from_table(rep, n_b, extent, MIN_PTS, use_ref=True)
+        _assert_nearest(di, dd, x, rep, delta_q, f"extreme assign dense d={d}")
+        _assert_nearest(gi, gd, x, rep, delta_q, f"extreme assign pruned d={d}")
+        Wd, rd = ops.offline_recluster_from_table(
+            rep, n_b, extent, MIN_PTS, use_ref=True, return_w=True
+        )
         rs = ops.offline_recluster_from_table(
             rep, n_b, extent, MIN_PTS, use_ref=True, spatial_index=True
         )
-        _bitwise(rd.labels, rs.labels, f"singleton labels d={d}")
+        _assert_pass(rd, Wd, rep, n_b, extent, MIN_PTS, f"extreme dense d={d}")
+        _assert_pass(rs, None, rep, n_b, extent, MIN_PTS, f"extreme spatial d={d}")
 
 
 class TestServePlane:
@@ -375,12 +599,24 @@ class TestServePlane:
 
         s_d, r_d = run(False)
         s_s, r_s = run(True)
+        # the online summary is host float64 and the same on both engines
         assert s_d.n_bubbles == s_s.n_bubbles
-        _bitwise(s_d.bubble_labels, s_s.bubble_labels, "engine labels")
-        _bitwise(r_d.labels, r_s.labels, "query labels")
-        _bitwise(r_d.bubble_index, r_s.bubble_index, "query idx")
-        _bitwise(r_d.distance, r_s.distance, "query dist")
-        _bitwise(r_d.strength, r_s.strength, "query strength")
+        np.testing.assert_array_equal(s_d.bubble_rep, s_s.bubble_rep)
+        np.testing.assert_array_equal(s_d.bubble_n, s_s.bubble_n)
+        for name, s, r in (("dense", s_d, r_d), ("spatial", s_s, r_s)):
+            _assert_labels(s.result, s.bubble_n, 5, f"engine labels {name}")
+            delta = _delta(Q - s.center, s.bubble_rep - s.center)
+            _assert_nearest(r.bubble_index, r.distance, Q, s.bubble_rep, delta, f"query {name}")
+            np.testing.assert_array_equal(
+                r.labels, s.bubble_labels[r.bubble_index], err_msg=f"query labels {name}"
+            )
+        # three separated blobs: both passes find the same clusters, and
+        # each query row is served the same cluster and strength
+        names = _assert_same_partition(s_d.bubble_labels, s_s.bubble_labels, "engine")
+        np.testing.assert_array_equal(
+            [names.get(k, -1) for k in r_d.labels.tolist()], r_s.labels, "query labels"
+        )
+        np.testing.assert_allclose(r_d.strength, r_s.strength, rtol=1e-4, atol=1e-6)
 
 
 # ---------------------------------------------------------------------------
@@ -456,20 +692,17 @@ class TestGridProperties:
     @settings(max_examples=10, deadline=None)
     def test_candidates_contain_true_knn(self, seed, d):
         # end-to-end form of the no-pruned-neighbor property: the pruned
-        # nearest/top-K results equal the jitted dense reference exactly,
-        # which is impossible if any true neighbor were ever pruned
+        # core distances and nearest bubbles are the float64 oracle's,
+        # which they cannot be if a true neighbor were ever pruned
         pts, valid = _draw_grid(seed, d, 0.0)
         rng = np.random.default_rng(seed + 1)
         n_b = rng.integers(1, 6, _PL).astype(np.float32)
         extent = np.abs(rng.normal(0.2, 0.05, _PL)).astype(np.float32)
-        dense = ops.bubble_core_distances(pts, n_b, extent, MIN_PTS, use_ref=True)
         pruned = ops.bubble_core_distances(pts, n_b, extent, MIN_PTS, spatial_index=True)
-        _bitwise(dense, pruned, f"prop cd seed={seed} d={d}")
+        _assert_cd(pruned, pts, n_b, extent, MIN_PTS, _delta(pts), f"prop cd seed={seed} d={d}")
         x = rng.normal(0.0, 3.5, (32, d)).astype(np.float32)
-        di, dd = _assign_dense(x, pts, use_ref=True)
         gi, gd = _assign_grid(x, pts)
-        _bitwise(di, gi, f"prop assign idx seed={seed}")
-        _bitwise(dd, gd, f"prop assign dist seed={seed}")
+        _assert_nearest(gi, gd, x, pts, _delta(x, pts), f"prop assign seed={seed}")
 
     @given(st.integers(0, 10_000), st.sampled_from([0.3, 0.7]))
     @settings(max_examples=10, deadline=None)
@@ -482,7 +715,8 @@ class TestGridProperties:
         idx = np.asarray(idx)
         assert valid[idx].all(), "assignment landed on an invalid row"
         # the CONTENTS of invalid rows are irrelevant: scribble garbage
-        # into them and every output bit on valid rows must be unchanged
+        # into them and every result on valid rows stays on the oracle
+        # over the valid rows alone
         pts2 = pts.copy()
         pts2[~valid] = (
             np.random.default_rng(seed + 3)
@@ -490,23 +724,24 @@ class TestGridProperties:
             .astype(np.float32)
         )
         g2 = build_grid(jnp.asarray(pts2), jnp.asarray(valid))
-        idx2, m2 = grid_assign(g2, jnp.asarray(x))
-        _bitwise(idx, np.asarray(idx2), "invalid-contents idx")
+        idx2, _ = grid_assign(g2, jnp.asarray(x))
+        delta_q = _delta(x, pts[valid])
+        _assert_nearest(idx, None, x, pts, delta_q, "invalid-rows idx", valid=valid)
+        _assert_nearest(idx2, None, x, pts, delta_q, "invalid-contents idx", valid=valid)
         rng = np.random.default_rng(seed + 4)
         n_b = np.where(valid, rng.integers(1, 6, _PL), 0).astype(np.float32)
         extent = np.abs(rng.normal(0.2, 0.05, _PL)).astype(np.float32)
         mp = min(MIN_PTS, int(n_b.sum()))
-        cd1 = grid_core_distances(g, n_b, extent, mp, d)
-        cd2 = grid_core_distances(g2, n_b, extent, mp, d)
-        _bitwise(
-            np.asarray(cd1)[valid], np.asarray(cd2)[valid], "invalid-contents cd"
-        )
+        delta = _delta(pts[valid])
+        for name, grid in (("invalid-rows", g), ("invalid-contents", g2)):
+            cd = np.asarray(grid_core_distances(grid, n_b, extent, mp, d))[valid]
+            _assert_cd(cd, pts[valid], n_b[valid], extent[valid], mp, delta, f"{name} cd")
 
     @given(st.integers(0, 10_000))
     @settings(max_examples=8, deadline=None)
     def test_bucket_padding_invariance(self, seed):
-        # doubling the padded bucket (extra all-invalid tiles) must not
-        # change a single output bit on the real rows
+        # doubling the padded bucket (extra all-invalid tiles) leaves the
+        # real rows' results on the oracle
         d = 8
         rng = np.random.default_rng(seed)
         rep = rng.normal(0, 3, (_PL, d)).astype(np.float32)
@@ -526,8 +761,9 @@ class TestGridProperties:
             idx, m = grid_assign(g, jnp.asarray(x))
             return np.asarray(cd)[:_PL], np.asarray(idx), np.asarray(m)
 
-        cd1, i1, m1 = at_bucket(_PL)
-        cd2, i2, m2 = at_bucket(2 * _PL)
-        _bitwise(cd1, cd2, "padding cd")
-        _bitwise(i1, i2, "padding idx")
-        _bitwise(m1, m2, "padding m")
+        xx = np.sum(x.astype(np.float32) ** 2, axis=-1)
+        for Lp in (_PL, 2 * _PL):
+            cd, idx, m = at_bucket(Lp)
+            _assert_cd(cd, rep, n_b, extent, MIN_PTS, _delta(rep), f"padding cd Lp={Lp}")
+            dist = np.sqrt(np.maximum(xx + m, 0.0))
+            _assert_nearest(idx, dist, x, rep, _delta(x, rep), f"padding assign Lp={Lp}")

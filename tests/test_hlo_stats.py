@@ -1,14 +1,11 @@
-"""launch/hlo_stats.py + launch/dryrun.py unit coverage (ISSUE 10).
+"""launch/hlo_stats.py unit coverage.
 
-Three layers, all fast (no 512-device subprocess, unlike test_dryrun.py):
+Two layers, all fast:
 
   * the pure shape-string helpers on synthetic inputs,
   * ``analyze_module`` round-tripped against REAL compiled HLO (CPU) where
     the expected flops are known in closed form, plus a synthetic module
-    exercising the collective link-bytes model and the cross-pod split,
-  * ``dryrun.build_cell`` as a shape-only trace: every leaf it hands back
-    is abstract, ``jax.eval_shape`` runs the full step, and no
-    model-scale buffer is ever allocated.
+    exercising the collective link-bytes model and the cross-pod split.
 """
 
 import jax
@@ -130,42 +127,3 @@ class TestRoofline:
     def test_zero_is_well_defined(self):
         r = H.roofline_terms(flops=0.0, hbm_bytes=0.0, link_bytes=0.0)
         assert r["bound_s"] == 0.0 and r["roofline_fraction"] == 0.0
-
-
-class TestDryrunShapeOnly:
-    """build_cell is a shape-only planner: abstract in, abstract out."""
-
-    def test_train_cell_traces_without_allocating(self):
-        from repro.configs import SHAPES, get
-        from repro.launch import dryrun as DR
-        from repro.launch import sharding as SH
-
-        cfg = get("qwen1.5-0.5b")
-        shape = SHAPES["train_4k"]
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
-        before = {id(a) for a in jax.live_arrays()}
-        with SH.use_mesh(mesh):
-            fn, args, in_sh, out_sh, donate, meta = DR.build_cell(cfg, shape, mesh)
-            leaves = jax.tree.leaves(args)
-            assert leaves, "train cell must have inputs"
-            assert all(isinstance(x, jax.ShapeDtypeStruct) for x in leaves)
-            out = jax.eval_shape(fn, *args)
-        assert all(isinstance(x, jax.ShapeDtypeStruct) for x in jax.tree.leaves(out))
-        # params round-trip: the step's first output matches its first input
-        assert jax.tree.map(lambda s: (s.shape, s.dtype), out[0]) == jax.tree.map(
-            lambda s: (s.shape, s.dtype), args[0]
-        )
-        assert meta["microbatches"] >= 1
-        # no model-scale buffer may materialize from a shape-only build:
-        # a 0.5B-param model is ~2 GB; trace-time constants stay < 1 MB
-        new = [a for a in jax.live_arrays() if id(a) not in before]
-        assert sum(a.size * a.dtype.itemsize for a in new) < (1 << 20)
-
-    def test_batch_specs_shard_leading_dim_when_divisible(self):
-        from repro.launch import dryrun as DR
-
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
-        specs = {"tokens": jax.ShapeDtypeStruct((8, 128), jnp.int32)}
-        sh = DR.batch_specs(mesh, specs, "train")
-        # 1-device mesh: no axis has size > 1, so everything replicates
-        assert sh["tokens"].spec == jax.sharding.PartitionSpec(None, None)

@@ -165,7 +165,7 @@ class TestShardedOfflinePass:
     def test_matches_single_device(self, rng):
         """Row-sharded d_m strip computation == single-device kernel (the
         distributed offline pass; multi-device equivalence is exercised by
-        the 8-device subprocess in tests/test_dryrun.py environments)."""
+        the 1/2/8-device subprocesses in tests/test_mesh_sharding.py)."""
         L, d = 23, 4
         rep = rng.normal(size=(L, d)).astype(np.float32)
         nb = (np.abs(rng.normal(size=L)) * 10 + 1).astype(np.float32)

@@ -15,8 +15,8 @@ Two layers of guarantees:
     1, 2, and 8 simulated devices run the identical scenario suite
     (fused dense + spatial, device-table path, streaming engine end to
     end) and their result digests must be identical byte for byte
-    (pattern from test_dryrun.py — the parent process's jax device
-    count is never polluted).
+    (in subprocesses, so the parent process's jax device count is never
+    polluted).
 
 Run `python tests/test_mesh_sharding.py --digest` to print one
 process's digests (the worker mode the subprocess test drives).

@@ -1,10 +1,9 @@
 """Serve-plane query subsystem (ISSUE 5): versioned device cache, fused
 query program, input validation, labels() memoization, QueryBatcher.
 
-The differential contract: the device-cached path must agree with (a)
-the PR 4-era per-call upload path (`query_percall`, kept verbatim as the
-oracle) and (b) a pure-host f64 nearest-bubble replay — up to genuine
-argmin ties, which are accepted via a near-nearest distance check.
+The differential contract: the device-cached path must agree with a
+pure-host f64 nearest-bubble replay — up to genuine argmin ties, which
+are accepted via a near-nearest distance check.
 """
 
 import threading
@@ -14,7 +13,7 @@ import pytest
 
 from conftest import make_blobs
 from repro.serving import QueryBatcher, StreamingClusterEngine
-from repro.serving.query import query_percall, validate_query
+from repro.serving.query import validate_query
 
 BACKENDS = pytest.mark.parametrize(
     "backend", ["jnp", "pallas"], ids=["jnp", "pallas"]
@@ -30,6 +29,28 @@ def _engine(backend, rng, n_per=60, **kw):
     eng.ingest(X)
     eng.flush()
     return eng, X
+
+
+def _build_query_snapshot(L: int, d: int, seed: int):
+    """Serving-scale `ClusterSnapshot` straight from a synthetic bubble
+    table through the real fused offline pass — the query benches need a
+    published snapshot, not a whole ingestion run."""
+    from repro.kernels import ops as kops
+    from repro.serving.stream import ClusterSnapshot
+
+    rng = np.random.default_rng(seed)
+    centers = rng.normal(size=(8, d)) * 10.0
+    rep = centers[rng.integers(0, 8, size=L)] + rng.normal(size=(L, d)) * 0.5
+    n_b = rng.integers(5, 50, size=L).astype(np.float64)
+    extent = np.abs(rng.normal(size=L)) * 0.3
+    res = kops.offline_recluster_from_table(
+        rep, n_b, extent, min_pts=10, min_cluster_size=10.0, use_ref=True
+    )
+    center = (n_b @ rep) / n_b.sum()
+    return ClusterSnapshot(
+        version=1, n_points=int(n_b.sum()), bubble_rep=rep, bubble_n=n_b,
+        center=center, result=res, wall_seconds=0.0,
+    )
 
 
 def _host_nearest(snap, X):
@@ -109,15 +130,11 @@ class TestValidation:
 
 class TestParity:
     @BACKENDS
-    def test_cached_matches_percall_and_host_replay(self, backend, rng):
+    def test_cached_matches_host_replay(self, backend, rng):
         eng, X = _engine(backend, rng)
         snap = eng.snapshot
         Q = np.concatenate([X, rng.normal(size=(40, 2)) * 3.0])
         res = eng.query_detailed(Q)
-        # per-call oracle runs the same f32 kernel — labels must agree
-        np.testing.assert_array_equal(
-            res.labels, query_percall(eng.backend, snap, Q)
-        )
         assert_replay_matches(snap, Q, res)
         # distance parity vs f64 replay (f32 expansion tolerance)
         _, sq = _host_nearest(snap, Q)
@@ -181,7 +198,6 @@ class TestParity:
         up λ_max and collapse every sibling's strength to ~0."""
         import dataclasses as dc
 
-        from benchmarks.fig5_latency import _build_query_snapshot
         from repro.serving.query import QueryEngine
 
         snap = _build_query_snapshot(64, 4, seed=3)

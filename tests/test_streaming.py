@@ -7,6 +7,10 @@ Covers the three contract points of the online–offline service:
     offline MST total weight (the hierarchy invariant).
 """
 
+import os
+import subprocess
+import sys
+
 import numpy as np
 import pytest
 
@@ -14,7 +18,7 @@ from conftest import make_blobs
 from repro.core.bubble_tree import BubbleTree
 from repro.core.metrics import nmi
 from repro.kernels import ops
-from repro.serving.engine import HostBatcher
+from repro.serving.batcher import HostBatcher
 from repro.serving.stream import StreamingClusterEngine
 
 
@@ -44,6 +48,23 @@ class TestHostBatcher:
         b.push("x", kind="req")
         assert b.pop_one() == "x"
         assert len(b) == 0
+
+
+def test_serving_does_not_load_the_lm_stack():
+    """The clustering service imports none of the language-model
+    template (models, configs, train)."""
+    code = (
+        "import sys, repro.serving, repro.serving.stream, repro.serving.query\n"
+        "print(sorted(m for m in sys.modules if m.split('.')[:2] in "
+        "(['repro', 'models'], ['repro', 'configs'], ['repro', 'train'])))"
+    )
+    src = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src")
+    env = dict(os.environ, PYTHONPATH=src, JAX_PLATFORMS="cpu")
+    r = subprocess.run(
+        [sys.executable, "-c", code], capture_output=True, text=True, env=env, timeout=300
+    )
+    assert r.returncode == 0, r.stderr[-2000:]
+    assert r.stdout.strip().splitlines()[-1] == "[]", r.stdout
 
 
 class TestBatchedDelete:

@@ -7,7 +7,7 @@ Rule families (see DESIGN.md §13 for the contract each one pins):
   RPL3xx  serve-plane lock discipline            (DESIGN §9, §10, §11)
   RPL4xx  Pallas / kernel hygiene                (DESIGN §2, §6, §12)
 
-Entry point: ``python -m tools.lint src tests benchmarks scripts``.
+Entry point: ``python -m tools.lint src tests scripts``.
 Suppress a finding inline with ``# repro-lint: disable=RPL101`` (same line
 or a standalone comment line directly above).  Grandfathered findings live
 in ``tools/lint/baseline.txt``; quarantine a whole template-era file with a

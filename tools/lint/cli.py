@@ -1,4 +1,4 @@
-"""Command-line driver: ``python -m tools.lint src tests benchmarks scripts``.
+"""Command-line entry point: ``python -m tools.lint src tests scripts``.
 
 Exit codes:
   0  clean (no findings beyond the committed baseline)
@@ -126,7 +126,7 @@ def main(argv: list[str] | None = None) -> int:
         prog="python -m tools.lint",
         description="repro-lint: static checks for this repo's DESIGN.md contracts",
     )
-    ap.add_argument("paths", nargs="*", default=["src", "tests", "benchmarks", "scripts"])
+    ap.add_argument("paths", nargs="*", default=["src", "tests", "scripts"])
     ap.add_argument("--root", default=".", help="repo root (paths resolve against it)")
     ap.add_argument("--baseline", default=DEFAULT_BASELINE)
     ap.add_argument(

@@ -35,9 +35,6 @@ HOST_ORACLE_PATH = r"(^|/)core/(bubble_tree|hdbscan|dynamic)\.py$"
 F32_HANDOFF_ENTRY_POINTS: list[tuple[str, str]] = [
     (r"(^|/)kernels/ops\.py$", "cluster_bubbles"),
     (r"(^|/)serving/query\.py$", "_build_entry"),
-    (r"(^|/)benchmarks/fig7_scalability\.py$", "run_pruned"),
-    (r"(^|/)benchmarks/fig7_scalability\.py$", "run_mesh"),
-    (r"(^|/)benchmarks/fig8_streaming\.py$", "run"),
 ]
 
 # a subtraction whose right operand looks like a centroid/origin — the
